@@ -62,7 +62,8 @@ def _run_model(name: str, layerwise: bool):
                           max_elems=100)
         o_bad = inj.inject(o_clean, spec, model)
         f = jax.jit(lambda p_, x_, o_: cnn.forward_cnn(
-            p_, x_, cfg, plan=plan, inject_layer=layer, inject_o=o_))
+            p_, x_, cfg, plan=plan, inject_layer=layer,
+            inject_o={layer: o_}))
         logits, rep = f(params, x, o_bad)
         total += time_fn(f, params, x, o_bad)
         corrected.append(int(rep.corrected_by))

@@ -30,9 +30,10 @@ def run():
                        f"overhead_pct={(t1-t0)/t0*100:.2f}"))
         # structural traffic: separate encode re-reads O (n*m*4B) +
         # re-reads D (n*k*4B); fused epilogue writes only the partials
-        bm = bn = 256
+        # (3 rows of m per 256-row tile)
+        bm = 256
         sep = (n * m + n * k) * 4
-        fused = (m * (n // bm) + n * (m // bn) + (n // bm) * (m // bn)) * 4
+        fused = 3 * m * (n // bm) * 4
         out.append(row(f"kernels/fused_traffic/{n}x{k}x{m}", 0.0,
                        f"separate_encode_bytes={sep};"
                        f"fused_partial_bytes={fused};"

@@ -378,7 +378,8 @@ def run(out_path: str | None = None):
 
     mesh = None
     if jax.device_count() >= 4:
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(2, 2)
 
     # the parity oracle: unbatched, unprotected greedy continuation
     refs = [greedy_reference(params, ucfg, p, GEN, MAX_LEN)

@@ -18,6 +18,8 @@ def main() -> None:
                     help="skip the slow erroneous/parallel/campaign suites")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_campaign, bench_error_free, bench_erroneous,
                    bench_kernels, bench_mm_abft, bench_parallel, bench_plan,
                    bench_schemes, bench_serve, bench_transformer, roofline)

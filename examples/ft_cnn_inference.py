@@ -52,7 +52,8 @@ def main():
                      o_clean.shape[1], max_elems=100)
         o_bad = inj.inject_conv(o_clean, p)
         logits, rep = cnn.forward_cnn(params, x, cfg, plan=plan,
-                                      inject_layer=layer, inject_o=o_bad)
+                                      inject_layer=layer,
+                                      inject_o={layer: o_bad})
         r = rep.by_layer[f"conv{layer}"]          # per-layer attribution
         top1 = np.argmax(np.asarray(logits), -1)
         status = "OK " if np.array_equal(top1, clean_top1) else "DIFF"

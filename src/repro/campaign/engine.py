@@ -245,7 +245,7 @@ def _matmul_trial(case: MatmulCase, cfg: T.ProtectConfig, max_elems: int,
         kd, kw, kf = jax.random.split(key, 3)
         d = jax.random.normal(kd, (case.n, case.k), F32)
         w = jax.random.normal(kw, (case.k, case.m), F32)
-        o_ref, _ = ref.abft_matmul_ref(d, w, bm=case.n, bn=case.m)
+        o_ref, _ = ref.abft_matmul_ref(d, w, bm=case.n)
         # the ProtectionPlan path: weight checksums encoded once per trial
         # weight draw (the offline step), then handed to the unified op.
         # Weight-target models corrupt W *after* this encode (stale-plan
@@ -253,7 +253,7 @@ def _matmul_trial(case: MatmulCase, cfg: T.ProtectConfig, max_elems: int,
         # while the entry still carries the clean-plan checksums.
         entry = matmul_entry("cell", w, cfg)
         w_run = inject_w(kf, model_id, w)
-        o_run, _ = ref.abft_matmul_ref(d, w_run, bm=case.n, bn=case.m)
+        o_run, _ = ref.abft_matmul_ref(d, w_run, bm=case.n)
         o_bad = inject_o(kf, model_id, o_run)
         if deferred:
             out, rep = _deferred_protect(entry, d, w_run, o_bad)
@@ -263,8 +263,7 @@ def _matmul_trial(case: MatmulCase, cfg: T.ProtectConfig, max_elems: int,
         if _weight_correctable_ids(models):
             wrep = _weight_repair_outcome(
                 entry, w_run, o_ref,
-                lambda wf: ref.abft_matmul_ref(d, wf, bm=case.n,
-                                               bn=case.m)[0])
+                lambda wf: ref.abft_matmul_ref(d, wf, bm=case.n)[0])
             outcome = _merge_weight_repair(models, model_id, outcome, wrep)
         return outcome
 
@@ -286,11 +285,11 @@ def _transformer_gemm_trial(case: TransformerGemmCase, cfg: T.ProtectConfig,
         kd, kw, kf = jax.random.split(key, 3)
         d = jax.random.normal(kd, (case.n, case.k), F32)
         w = jax.random.normal(kw, (case.k, case.m), F32)
-        o_ref, _ = ref.abft_matmul_ref(d, w, bm=case.n, bn=case.m)
+        o_ref, _ = ref.abft_matmul_ref(d, w, bm=case.n)
         plan = ProtectionPlan(entries={
             "blk/ffn/gate": matmul_entry("blk/ffn/gate", w, cfg)})
         w_run = inject_w(kf, model_id, w)
-        o_run, _ = ref.abft_matmul_ref(d, w_run, bm=case.n, bn=case.m)
+        o_run, _ = ref.abft_matmul_ref(d, w_run, bm=case.n)
         o_bad = inject_o(kf, model_id, o_run)
         with plan_scope(plan), path_scope("blk", "ffn"):
             entry = resolve_entry("gate")
@@ -305,8 +304,7 @@ def _transformer_gemm_trial(case: TransformerGemmCase, cfg: T.ProtectConfig,
             if _weight_correctable_ids(models):
                 wrep = _weight_repair_outcome(
                     entry, w_run, o_ref,
-                    lambda wf: ref.abft_matmul_ref(d, wf, bm=case.n,
-                                                   bn=case.m)[0])
+                    lambda wf: ref.abft_matmul_ref(d, wf, bm=case.n)[0])
                 outcome = _merge_weight_repair(models, model_id, outcome,
                                                wrep)
         return outcome

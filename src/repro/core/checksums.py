@@ -7,7 +7,9 @@ every identity of the paper holds verbatim with per-block payload P=1.
 Conv view (paper's native form): D[N,Ch,H,H], W[M,Ch,R,R], O[N,M,E,E];
 blocks are the 3D substructures and the payload is the E*E output map.
 
-All checksums are carried in fp32 regardless of the operand dtype.
+All checksums are carried in fp32 regardless of the operand dtype. They
+encode the operand values the op multiplies (`types.op_operand`), and
+every product here runs at `types.PRECISION`.
 """
 from __future__ import annotations
 
@@ -18,7 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .types import OutputChecksums, OutputSums
+from .types import (PRECISION, OutputChecksums, OutputSums, op_operand,
+                    op_operand_dtype)
 
 F32 = jnp.float32
 
@@ -27,23 +30,28 @@ def _iota(n: int) -> jnp.ndarray:
     return jnp.arange(n, dtype=F32)
 
 
+def _mm(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """a @ b at the protected path's precision."""
+    return jnp.matmul(a, b, precision=PRECISION)
+
+
 # --------------------------------------------------------------------------
 # matmul path
 # --------------------------------------------------------------------------
 
 def encode_d_matmul(d: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """C_d1, C_d2 of D[N,K] (fp32). One pass over D; XLA fuses both sums."""
-    d32 = d.astype(F32)
+    d32 = op_operand(d)
     cd1 = jnp.sum(d32, axis=0)
-    cd2 = _iota(d.shape[0]) @ d32
+    cd2 = _mm(_iota(d.shape[0]), d32)
     return cd1, cd2
 
 
 def encode_w_matmul(w: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """C_w1, C_w2 of W[K,M] (fp32). Precomputable for weight-stationary ops."""
-    w32 = w.astype(F32)
+    w32 = op_operand(w)
     cw1 = jnp.sum(w32, axis=1)
-    cw2 = w32 @ _iota(w.shape[1])
+    cw2 = _mm(w32, _iota(w.shape[1]))
     return cw1, cw2
 
 
@@ -56,11 +64,11 @@ def output_sums_matmul(o: jnp.ndarray) -> OutputSums:
     wm = _iota(m)
     s1 = jnp.sum(o32, axis=0)          # (M,)
     s2 = jnp.sum(o32, axis=1)          # (N,)
-    s3 = wn @ o32                      # (M,)
-    s4 = o32 @ wm                      # (N,)
+    s3 = _mm(wn, o32)                  # (M,)
+    s4 = _mm(o32, wm)                  # (N,)
     s5 = jnp.sum(s1)
-    s6 = jnp.dot(wn, s2)               # sum_n n * rowsum
-    s7 = jnp.dot(s1, wm)
+    s6 = _mm(wn, s2)                   # sum_n n * rowsum
+    s7 = _mm(s1, wm)
     sumsq = jnp.sum(o32 * o32)
     return OutputSums(s1[:, None], s2[:, None], s3[:, None], s4[:, None],
                       s5[None], s6[None], s7[None], sumsq)
@@ -73,16 +81,16 @@ def output_checksums_matmul(
     need_rowcol: bool = True,
 ) -> OutputChecksums:
     """C_o1..C_o7. The scalar triple is O(K); c1..c4 are single GEMVs."""
-    c5 = jnp.dot(cd1, cw1)[None]
-    c6 = jnp.dot(cd2, cw1)[None]
-    c7 = jnp.dot(cd1, cw2)[None]
+    c5 = _mm(cd1, cw1)[None]
+    c6 = _mm(cd2, cw1)[None]
+    c7 = _mm(cd1, cw2)[None]
     if need_rowcol:
-        w32 = w.astype(F32)
-        d32 = d.astype(F32)
-        c1 = (cd1 @ w32)[:, None]
-        c2 = (d32 @ cw1)[:, None]
-        c3 = (cd2 @ w32)[:, None]
-        c4 = (d32 @ cw2)[:, None]
+        w32 = op_operand(w)
+        d32 = op_operand(d)
+        c1 = _mm(cd1, w32)[:, None]
+        c2 = _mm(d32, cw1)[:, None]
+        c3 = _mm(cd2, w32)[:, None]
+        c4 = _mm(d32, cw2)[:, None]
     else:
         c1 = c2 = c3 = c4 = None
     return OutputChecksums(c1, c2, c3, c4, c5, c6, c7)
@@ -90,7 +98,7 @@ def output_checksums_matmul(
 
 def absdot_matmul(cd1: jnp.ndarray, cw1: jnp.ndarray) -> jnp.ndarray:
     """|C_d1| . |C_w1| - checksum-side magnitude for the threshold model."""
-    return jnp.dot(jnp.abs(cd1), jnp.abs(cw1))
+    return _mm(jnp.abs(cd1), jnp.abs(cw1))
 
 
 # --------------------------------------------------------------------------
@@ -100,14 +108,59 @@ def absdot_matmul(cd1: jnp.ndarray, cw1: jnp.ndarray) -> jnp.ndarray:
 _DN = ("NCHW", "OIHW", "NCHW")
 
 
+def _window_pads(hw: Tuple[int, int], rs: Tuple[int, int], stride: int,
+                 padding) -> Tuple[Tuple[int, int], ...]:
+    if isinstance(padding, str):
+        return tuple(jax.lax.padtype_to_pads(hw, rs, (stride, stride),
+                                             padding))
+    return tuple((int(lo), int(hi)) for lo, hi in padding)
+
+
+def checksum_conv(x: jnp.ndarray, f: jnp.ndarray, stride: int = 1,
+                  padding="VALID", groups: int = 1) -> jnp.ndarray:
+    """conv(x[B,C,H,W], f[F,C/G,R,S]) -> (B, F, E1, E2) in f32, for the
+    checksum side, where B or F is a handful of checksum blocks.
+
+    Runs as im2col (static strided slices) + ONE contraction at PRECISION
+    instead of the conv primitive: XLA's TPU emitter for an f32 HIGHEST
+    conv with a tiny batch or feature dimension takes seconds to minutes
+    per conv to compile (it can fail and retry), while the equivalent dot
+    is routine. The patches are R*S times the image side - checksum-sized
+    for the c1/c3/c5-c7 convs, the fmap for c2/c4, which only the
+    correction branch computes.
+    """
+    with jax.named_scope("checksum_conv"):
+        return _checksum_conv(x, f, stride, padding, groups)
+
+
+def _checksum_conv(x, f, stride, padding, groups):
+    b, c, h, w = x.shape
+    nf, cg, r, s_ = f.shape
+    (pt, pb), (pl, pr) = _window_pads((h, w), (r, s_), stride, padding)
+    xp = jnp.pad(x.astype(F32), ((0, 0), (0, 0), (pt, pb), (pl, pr)))
+    e1 = (h + pt + pb - r) // stride + 1
+    e2 = (w + pl + pr - s_) // stride + 1
+    cols = [xp[:, :, i:i + stride * (e1 - 1) + 1:stride,
+               j:j + stride * (e2 - 1) + 1:stride]
+            for i in range(r) for j in range(s_)]
+    # (B, C, R*S, E1, E2) -> (B, G, C/G*R*S, P): (c, i, j)-major, as f's
+    pat = jnp.stack(cols, axis=2).reshape(b, groups, cg * r * s_, e1 * e2)
+    fm = f.astype(F32).reshape(groups, nf // groups, cg * r * s_)
+    out = jnp.einsum("bgkp,gfk->bgfp", pat, fm, precision=PRECISION)
+    return out.reshape(b, nf, e1, e2)
+
+
 def conv2d(d: jnp.ndarray, w: jnp.ndarray, stride: int = 1,
            padding="VALID", groups: int = 1) -> jnp.ndarray:
-    """The unprotected convolution (paper Eq. 1 without bias). XLA is free
-    to choose its implementation - the checksums sit above it."""
+    """The unprotected convolution (paper Eq. 1 without bias), on the
+    operands of `types.op_operand_dtype`. XLA is free to choose its
+    implementation - the checksums sit above it."""
+    dt = op_operand_dtype(d.dtype)
     return jax.lax.conv_general_dilated(
-        d, w, window_strides=(stride, stride), padding=padding,
+        d.astype(dt), w.astype(dt), window_strides=(stride, stride),
+        padding=padding,
         dimension_numbers=_DN, feature_group_count=groups,
-        preferred_element_type=F32).astype(d.dtype)
+        precision=PRECISION, preferred_element_type=F32).astype(d.dtype)
 
 
 def encode_d_conv(d: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -121,7 +174,7 @@ def encode_d_conv(d: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     thresholds already price in."""
     n = d.shape[0]
     enc = jnp.stack([jnp.ones((n,), F32), _iota(n)])
-    cd = (enc @ d.astype(F32).reshape(n, -1)).reshape(2, *d.shape[1:])
+    cd = _mm(enc, op_operand(d).reshape(n, -1)).reshape(2, *d.shape[1:])
     return cd[0], cd[1]
 
 
@@ -133,18 +186,19 @@ def encode_w_conv(w: jnp.ndarray, groups: int = 1
     group and concatenated along the channel axis so the result convolves
     with the full-channel fmap blocks.
     """
-    w32 = w.astype(F32)
+    w32 = op_operand(w)
     m = w.shape[0]
     if groups == 1:
         cw1 = jnp.sum(w32, axis=0)
-        cw2 = jnp.tensordot(_iota(m), w32, axes=1)
+        cw2 = jnp.tensordot(_iota(m), w32, axes=1, precision=PRECISION)
         return cw1, cw2
     mg = m // groups
     wg = w32.reshape(groups, mg, *w32.shape[1:])       # (G, M/G, Ch/G, R, R)
     weights = _iota(m).reshape(groups, mg)
     cw1 = jnp.concatenate(list(jnp.sum(wg, axis=1)), axis=0)   # (Ch, R, R)
     cw2 = jnp.concatenate(
-        list(jnp.einsum("gm,gmchw->gchw", weights, wg)), axis=0)
+        list(jnp.einsum("gm,gmchw->gchw", weights, wg,
+                        precision=PRECISION)), axis=0)
     return cw1, cw2
 
 
@@ -173,17 +227,13 @@ def detect_sums(o: jnp.ndarray, *, use_kernel: bool = False,
 
     `use_kernel=True` routes the pass through the Pallas single-pass
     reduction on the flattened (N*M, E*E) view (the same partials the
-    fused matmul epilogue emits); it falls back to the jnp pass when
-    the view does not tile.
+    fused matmul epilogue emits); `interpret` is then required.
     """
     if use_kernel and not exact_order:  # exact_order pins jnp's reduction order
-        from repro.kernels import ops as kops  # lazy: core must not need pallas
+        from repro.kernels import ops as kops  # lazy: keeps pallas off import
         if interpret is None:
-            from .types import default_kernel_interpret
-            interpret = default_kernel_interpret()
-        out = kops.conv_detect_sums(o, interpret=interpret, tiles=tiles)
-        if out is not None:
-            return out
+            raise ValueError("detect_sums(use_kernel=True) needs interpret=")
+        return kops.conv_detect_sums(o, interpret=interpret, tiles=tiles)
     n, m, e1, e2 = o.shape
     p = e1 * e2
     if exact_order:
@@ -191,17 +241,17 @@ def detect_sums(o: jnp.ndarray, *, use_kernel: bool = False,
         s1 = jnp.sum(o32, axis=0)                       # (M, P) intermediate
         s2 = jnp.sum(o32, axis=1)                       # (N, P) intermediate
         s5 = jnp.sum(s1, axis=0)                        # (P,)
-        s6 = jnp.tensordot(_iota(n), s2, axes=1)        # (P,)
-        s7 = jnp.tensordot(_iota(m), s1, axes=1)        # (P,)
+        s6 = _mm(_iota(n), s2)                          # (P,)
+        s7 = _mm(_iota(m), s1)                          # (P,)
         sumsq = jnp.sum(o32 * o32)
         return s5, s6, s7, sumsq
     o2 = o.astype(F32).reshape(n * m, p)
     enc = jnp.stack([jnp.ones((n * m,), F32),
                      jnp.repeat(_iota(n), m),
                      jnp.tile(_iota(m), n)])            # constant-folded
-    s = enc @ o2
+    s = _mm(enc, o2)
     flat = o2.reshape(-1)
-    sumsq = jnp.vdot(flat, flat)
+    sumsq = jnp.vdot(flat, flat, precision=PRECISION)
     return s[0], s[1], s[2], sumsq
 
 
@@ -210,15 +260,15 @@ def detect_checksums_conv(
     cw1: jnp.ndarray, cw2: jnp.ndarray,
     stride: int = 1, padding="VALID",
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """(c5, c6, c7, absdot) for CoC-D in ONE batched convolution.
+    """(c5, c6, c7, absdot) for CoC-D in ONE batched `checksum_conv`.
 
     The three scalar-invariant checksum convs (cd1*cw1, cd2*cw1, cd1*cw2)
     and the |cd1|*|cw1| threshold conv share operands pairwise: stacking
     [cd1, cd2, |cd1|] as the batch and [cw1, cw2, |cw1|] as output channels
-    computes all four (plus five unused pairings) in a single conv
-    dispatch. The wasted pairings cost 9 block-convs total - ~9/(N*M) of
-    the protected op - while the old path paid four separate XLA conv
-    calls, which at CNN layer sizes is dispatch-bound, not FLOP-bound.
+    computes all four (plus five unused pairings) in a single contraction.
+    The wasted pairings cost 9 block-convs total - ~9/(N*M) of the
+    protected op - while four separate checksum convs would be four
+    dispatches, which at CNN layer sizes is dispatch-bound, not FLOP-bound.
 
     Grouped convs need no special case: cw1/cw2 already carry full
     channels, so the checksum convs are dense (the paper's SS5.2 identity).
@@ -227,9 +277,7 @@ def detect_checksums_conv(
                       jnp.abs(cd1).astype(F32)])
     wstk = jnp.stack([cw1.astype(F32), cw2.astype(F32),
                       jnp.abs(cw1).astype(F32)])
-    out = jax.lax.conv_general_dilated(
-        dstk, wstk, (stride, stride), padding, dimension_numbers=_DN,
-        preferred_element_type=F32)
+    out = checksum_conv(dstk, wstk, stride, padding)
     c5 = out[0, 0].reshape(-1)
     c6 = out[1, 0].reshape(-1)
     c7 = out[0, 1].reshape(-1)
@@ -246,11 +294,11 @@ def output_sums_conv(o: jnp.ndarray) -> OutputSums:
     wm = _iota(m)
     s1 = jnp.sum(o32, axis=0)                       # (M, P)
     s2 = jnp.sum(o32, axis=1)                       # (N, P)
-    s3 = jnp.tensordot(wn, o32, axes=1)             # (M, P)
-    s4 = jnp.einsum("nmp,m->np", o32, wm)           # (N, P)
+    s3 = jnp.tensordot(wn, o32, axes=1, precision=PRECISION)   # (M, P)
+    s4 = jnp.einsum("nmp,m->np", o32, wm, precision=PRECISION)  # (N, P)
     s5 = jnp.sum(s1, axis=0)                        # (P,)
-    s6 = jnp.tensordot(wn, s2, axes=1)              # (P,)
-    s7 = jnp.tensordot(wm, s1, axes=1)              # (P,)
+    s6 = _mm(wn, s2)                                # (P,)
+    s7 = _mm(wm, s1)                                # (P,)
     sumsq = jnp.sum(o32 * o32)
     return OutputSums(s1, s2, s3, s4, s5, s6, s7, sumsq)
 
@@ -262,39 +310,29 @@ def output_checksums_conv(
     stride: int = 1, padding="VALID", groups: int = 1,
     need_rowcol: bool = True,
 ) -> OutputChecksums:
-    """C_o1..C_o7 via tiny convolutions of the checksum blocks.
+    """C_o1..C_o7 via `checksum_conv` of the checksum blocks.
 
-    c1/c3 cost one batch-1 conv each; c2/c4 one single-output-channel conv;
-    c5/c6/c7 are 1x1-block convs - all negligible next to the NM-block op.
-    Grouped conv (paper SS5.2): cw1/cw2 already have full Ch channels, so the
-    checksum convs run as *dense* convs (groups=1) - this is exactly the
-    identity proved in the paper.
+    c1/c3 convolve the two fmap checksums with W, c2/c4 the fmap with the
+    two kernel checksums, c5/c6/c7 the checksums with each other - all
+    small next to the NM-block op. Grouped conv (paper SS5.2): cw1/cw2
+    already have full Ch channels, so every conv but c1/c3 (with W
+    itself) is *dense* - this is exactly the identity proved in the paper.
     """
-    cv = partial(jax.lax.conv_general_dilated, window_strides=(stride, stride),
-                 padding=padding, dimension_numbers=_DN,
-                 preferred_element_type=F32)
-    d32 = d.astype(F32)
-    w32 = w.astype(F32)
-
-    c5 = cv(cd1[None], cw1[None])[0, 0].reshape(-1)
-    c6 = cv(cd2[None], cw1[None])[0, 0].reshape(-1)
-    c7 = cv(cd1[None], cw2[None])[0, 0].reshape(-1)
+    cv = partial(checksum_conv, stride=stride, padding=padding)
+    cdd = jnp.stack([cd1, cd2])
+    cww = jnp.stack([cw1, cw2])
+    s = cv(cdd, cww)                                        # (2, 2, E, E)
+    c5 = s[0, 0].reshape(-1)
+    c6 = s[1, 0].reshape(-1)
+    c7 = s[0, 1].reshape(-1)
     if need_rowcol:
-        if groups == 1:
-            c1 = cv(cd1[None], w32)[0]                      # (M, E, E)
-            c3 = cv(cd2[None], w32)[0]
-        else:
-            c1 = jax.lax.conv_general_dilated(
-                cd1[None], w32, (stride, stride), padding,
-                dimension_numbers=_DN, feature_group_count=groups,
-                preferred_element_type=F32)[0]
-            c3 = jax.lax.conv_general_dilated(
-                cd2[None], w32, (stride, stride), padding,
-                dimension_numbers=_DN, feature_group_count=groups,
-                preferred_element_type=F32)[0]
-        c2 = cv(d32, cw1[None])[:, 0]                       # (N, E, E)
-        c4 = cv(d32, cw2[None])[:, 0]
-        c1, c2, c3, c4 = (x.reshape(x.shape[0], -1) for x in (c1, c2, c3, c4))
+        m, n = w.shape[0], d.shape[0]
+        c13 = cv(cdd, op_operand(w), groups=groups)        # (2, M, E, E)
+        c24 = cv(op_operand(d), cww)                        # (N, 2, E, E)
+        c1 = c13[0].reshape(m, -1)
+        c3 = c13[1].reshape(m, -1)
+        c2 = c24[:, 0].reshape(n, -1)
+        c4 = c24[:, 1].reshape(n, -1)
     else:
         c1 = c2 = c3 = c4 = None
     return OutputChecksums(c1, c2, c3, c4, c5, c6, c7)
@@ -349,9 +387,11 @@ def weight_locators_matmul(w, col_chunk: int) -> WeightLocators:
     if isinstance(w, jax.core.Tracer):
         w3 = w.astype(F32).reshape(k, mb, cb)
         r1 = jnp.einsum("kbc->bk", w3)
-        r2 = jnp.einsum("kbc,c->bk", w3, jnp.arange(cb, dtype=F32))
+        r2 = jnp.einsum("kbc,c->bk", w3, jnp.arange(cb, dtype=F32),
+                        precision=PRECISION)
         c1 = jnp.einsum("kbc->bc", w3)
-        c2 = jnp.einsum("kbc,k->bc", w3, jnp.arange(k, dtype=F32))
+        c2 = jnp.einsum("kbc,k->bc", w3, jnp.arange(k, dtype=F32),
+                        precision=PRECISION)
         return WeightLocators(r1, r2, c1, c2, cb)
     w3 = np.asarray(w).astype(np.float64).reshape(k, mb, cb)
     r1 = np.einsum("kbc->bk", w3)
@@ -372,9 +412,9 @@ def weight_locators_conv(w) -> WeightLocators:
     if isinstance(w, jax.core.Tracer):
         wf = w.astype(F32).reshape(m, j)
         r1 = jnp.sum(wf, axis=1)
-        r2 = wf @ jnp.arange(j, dtype=F32)
+        r2 = _mm(wf, jnp.arange(j, dtype=F32))
         c1 = jnp.sum(wf, axis=0)
-        c2 = jnp.arange(m, dtype=F32) @ wf
+        c2 = _mm(jnp.arange(m, dtype=F32), wf)
         return WeightLocators(r1, r2, c1, c2, 0)
     wf = np.asarray(w).astype(np.float64).reshape(m, j)
     iota_j = np.arange(j, dtype=np.float64)
@@ -388,7 +428,5 @@ def absdot_conv(cd1: jnp.ndarray, cw1: jnp.ndarray, stride: int = 1,
     """Checksum-magnitude scale for conv: |cd1| (x) |cw1| summed, one value
     per op (coarse upper bound is fine - it only guards the fp32 term).
     Uses the op's own stride/padding so the output is never empty."""
-    c = jax.lax.conv_general_dilated(
-        jnp.abs(cd1)[None], jnp.abs(cw1)[None], (stride, stride), padding,
-        dimension_numbers=_DN, preferred_element_type=F32)
-    return jnp.max(c)
+    return jnp.max(checksum_conv(jnp.abs(cd1)[None], jnp.abs(cw1)[None],
+                                 stride, padding))

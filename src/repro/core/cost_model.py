@@ -43,11 +43,6 @@ BYTES_F32 = 4
 _GEMM_N = 512
 _TRIAD_ELEMS = 1 << 22     # 16 MiB per operand array
 
-# conservative fallbacks (never negative-cost a scheme when the
-# microbench cannot run): a ~2010s-class core
-_FALLBACK_FLOPS = 5e9
-_FALLBACK_BW = 5e9
-
 
 @dataclasses.dataclass(frozen=True)
 class HostPeaks:
@@ -56,7 +51,7 @@ class HostPeaks:
     hbm_bw: float         # bytes/s sustained on a triad stream
     backend: str          # jax.default_backend() at measurement time
     host: str             # platform.node() at measurement time
-    source: str           # "measured" | "cache" | "fallback"
+    source: str           # "measured" | "cache"
 
     @property
     def ridge(self) -> float:
@@ -97,15 +92,17 @@ def _time_best(fn, *args, iters: int = 3, warmup: int = 1) -> float:
 
 
 def _bench_gemm_flops(n: int = _GEMM_N) -> float:
-    """Sustained f32 GEMM FLOP/s: 2*n^3 FLOPs over the best of a few
-    timed (n,n)@(n,n) products."""
+    """Sustained f32 GEMM FLOP/s in the protected op's arithmetic
+    (protected.op_matmul): 2*n^3 FLOPs over the best of a few timed
+    (n,n)@(n,n) products."""
     import jax
     import jax.numpy as jnp
+
+    from .protected import op_matmul
     key = jax.random.PRNGKey(0)
     a = jax.random.normal(key, (n, n), jnp.float32)
     b = jax.random.normal(jax.random.fold_in(key, 1), (n, n), jnp.float32)
-    f = jax.jit(lambda a, b: jnp.dot(a, b,
-                                     preferred_element_type=jnp.float32))
+    f = jax.jit(op_matmul)
     t = _time_best(f, a, b)
     return 2.0 * n ** 3 / max(t, 1e-9)
 
@@ -131,7 +128,8 @@ def measure_peaks(cache_path: Optional[str] = None, refresh: bool = False
     the cache; later calls (and other processes) load it, so plan builds
     are deterministic given the cache file. `refresh=True` re-measures
     and rewrites; a cache recorded under a different backend is treated
-    as stale and re-measured too."""
+    as stale and re-measured too. A microbench that cannot run raises:
+    made-up peaks would silently decide every plan verdict."""
     import jax
     backend = jax.default_backend()
     path = cache_path or default_cache_path(backend)
@@ -146,22 +144,17 @@ def measure_peaks(cache_path: Optional[str] = None, refresh: bool = False
                                  backend, doc.get("host", "?"), "cache")
         except (ValueError, KeyError, OSError):
             pass                       # unreadable cache: re-measure
-    try:
-        flops = _bench_gemm_flops()
-        bw = _bench_triad_bw()
-        source = "measured"
-    except Exception:                  # headless/broken backend: degrade
-        flops, bw, source = _FALLBACK_FLOPS, _FALLBACK_BW, "fallback"
+    flops = _bench_gemm_flops()
+    bw = _bench_triad_bw()
     host = platform.node() or "unknown"
-    if source == "measured":
-        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        with open(path, "w") as f:
-            json.dump({"schema": CACHE_SCHEMA, "backend": backend,
-                       "host": host, "peak_flops": flops, "hbm_bw": bw,
-                       "gemm_n": _GEMM_N, "triad_elems": _TRIAD_ELEMS,
-                       "measured_at": time.strftime("%Y-%m-%dT%H:%M:%S")},
-                      f, indent=2)
-    return HostPeaks(flops, bw, backend, host, source)
+    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+    with open(path, "w") as f:
+        json.dump({"schema": CACHE_SCHEMA, "backend": backend,
+                   "host": host, "peak_flops": flops, "hbm_bw": bw,
+                   "gemm_n": _GEMM_N, "triad_elems": _TRIAD_ELEMS,
+                   "measured_at": time.strftime("%Y-%m-%dT%H:%M:%S")},
+                  f, indent=2)
+    return HostPeaks(flops, bw, backend, host, "measured")
 
 
 # --------------------------------------------------------------------------
@@ -186,9 +179,9 @@ class MeasuredCostModel(CostModel):
     seconds. Adds roofline classification (`classify`), the
     kernel-profile pruning window (`should_profile`) and bandwidth-bound
     detection chunk sizing (`detect_chunk`)."""
-    peak_flops: float = _FALLBACK_FLOPS
-    hbm_bw: float = _FALLBACK_BW
-    source: str = "fallback"
+    peak_flops: float = dataclasses.field(kw_only=True)
+    hbm_bw: float = dataclasses.field(kw_only=True)
+    source: str = "given"
     # profile only shapes whose intensity/ridge ratio falls inside this
     # window: far-bandwidth-bound shapes never amortise a fused epilogue
     # and far-compute-bound shapes hide the detection pass entirely, so

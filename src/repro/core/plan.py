@@ -40,11 +40,12 @@ import numpy as np
 from . import checksums as C
 from .policy import (CostModel, OpShape, decide_rc_clc,
                      profile_conv_detect_kernel, profile_matmul_kernel)
-from .protected import (WeightChecksums, pick_chunk, protect_matmul_output,
+from .protected import (WeightChecksums, op_matmul, pick_chunk,
+                        protect_matmul_output,
                         protected_conv, protected_grouped_matmul,
                         protected_matmul, weight_checksums_matmul)
 from .types import (DEFAULT_CONFIG, DetectEvidence, FaultReport,
-                    ProtectConfig)
+                    ProtectConfig, op_operand_dtype, op_output)
 
 PLAN_SCHEMA = "repro.plan/v1"
 
@@ -363,6 +364,8 @@ def plan_scope(plan: Optional["ProtectionPlan"] = None, *,
     if mode not in PROTECT_MODES:
         raise ValueError(f"unknown plan_scope mode {mode!r} "
                          f"(have {PROTECT_MODES})")
+    if plan is not None:
+        plan.check_backend()
     ctx = _PlanContext(plan=plan, mode=mode, detected=detected)
     _CTX.append(ctx)
     try:
@@ -456,7 +459,15 @@ def protect_site(name: str, inputs, *, entry: Optional[PlanEntry] = None,
     path carries a detect-pass flag trust it (the ladder skips
     re-detection); sites inside a scan (whose evidence merged into the
     stage carry) re-derive their own flag.
+
+    Everything the site traces sits under a named scope of its path, so
+    compiled HLO and profiler traces attribute each kernel to its site.
     """
+    with jax.named_scope(current_path(name)):
+        return _protect_site(name, inputs, entry, cfg, o, op)
+
+
+def _protect_site(name, inputs, entry, cfg, o, op):
     if entry is None:
         entry = resolve_entry(name)
     if entry is not None:
@@ -494,11 +505,10 @@ def protect_site(name: str, inputs, *, entry: Optional[PlanEntry] = None,
             d2 = d.reshape(-1, k)
             # same spelling as protected_matmul's raw product, so rows the
             # hook leaves alone stay bitwise identical to the clean path
-            o2 = jnp.dot(d2, w, preferred_element_type=jnp.float32
-                         ).astype(d.dtype)
+            o2 = op_output(op_matmul(d2, w), d.dtype)
             if len(inputs) > 2:
-                o2 = (o2.astype(jnp.float32)
-                      + inputs[2].astype(jnp.float32)).astype(o2.dtype)
+                o2 = op_output(o2.astype(jnp.float32)
+                               + inputs[2].astype(jnp.float32), o2.dtype)
             o2 = hook(o2.reshape(*lead, m))
             out, rep = protect_op(op, (d2,) + tuple(inputs[1:]),
                                   entry=entry, cfg=use_cfg,
@@ -560,6 +570,20 @@ class ProtectionPlan:
                 for name, e in self.entries.items()}
 
     # -- staleness ---------------------------------------------------------
+    def check_backend(self) -> None:
+        """Raise PlanStaleError when this backend multiplies f32 operands
+        at another precision than the one the plan's weight checksums
+        encode (types.op_operand_dtype): a plan built on the CPU used on
+        a TPU would flag clean traffic at every f32 site. Plans that
+        predate the record were built for f32 operands."""
+        built = self.meta.get("f32_operands", "float32")
+        here = str(op_operand_dtype(jnp.float32))
+        if built != here:
+            raise PlanStaleError(
+                f"plan's weight checksums encode {built} operands for f32 "
+                f"weights, but this backend multiplies {here}; rebuild the "
+                "plan with build_plan() on this backend")
+
     def validate(self, params, rtol: float = 1e-5) -> None:
         """Raise PlanStaleError unless every entry's recorded weight
         shape/dtype AND content fingerprint match `params` (missing
@@ -1103,6 +1127,7 @@ def build_plan(params, arch_cfg, cost_model: Optional[CostModel] = None,
                                                 kprof.get(site.path))
     model = cost_model or CostModel()
     meta = dict(spec.meta)
+    meta["f32_operands"] = str(op_operand_dtype(jnp.float32))
     from .cost_model import cost_model_doc
     meta["cost_model"] = cost_model_doc(model)
     if measured:

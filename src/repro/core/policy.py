@@ -123,12 +123,14 @@ def _time_call(fn, *args, iters: int = 3, warmup: int = 2) -> float:
     return best
 
 
-_MATMUL_TILE_CANDIDATES = ((256, 256, 256), (128, 128, 256), (512, 512, 256))
+# bk 512: a GEMM with K <= 512 accumulates in one kernel step, as one
+# dot does, instead of adding per-step partial sums
+_MATMUL_TILE_CANDIDATES = ((256, 256, 512), (128, 128, 512), (512, 512, 512))
 
 
 def matmul_profile_programs(n: int, k: int, m: int, *,
                             tiles: Tuple[int, int, int],
-                            interpret: bool = True):
+                            interpret: bool):
     """The two candidate programs profile_matmul_kernel times, both
     finished to the SAME outputs (o, s5, s6, s7, sumsq):
 
@@ -144,16 +146,18 @@ def matmul_profile_programs(n: int, k: int, m: int, *,
     import jax
     import jax.numpy as jnp
 
+    from repro.core.protected import op_matmul
+    from repro.core.types import PRECISION
     from repro.kernels import ops as kops
     bm, bn, bk = tiles
 
     def plain(d, w):
-        o = jnp.dot(d, w, preferred_element_type=jnp.float32)
+        o = op_matmul(d, w)
         wn = jnp.arange(n, dtype=jnp.float32)
         wm = jnp.arange(m, dtype=jnp.float32)
         s5 = jnp.sum(o)
-        s6 = jnp.dot(wn, jnp.sum(o, axis=1))
-        s7 = jnp.dot(jnp.sum(o, axis=0), wm)
+        s6 = jnp.dot(wn, jnp.sum(o, axis=1), precision=PRECISION)
+        s7 = jnp.dot(jnp.sum(o, axis=0), wm, precision=PRECISION)
         return o, s5, s6, s7, jnp.sum(o * o)
 
     def fused(d, w):
@@ -222,11 +226,6 @@ def profile_conv_detect_kernel(o_shape: Tuple[int, int, int, int],
         interpret = default_kernel_interpret()
     o = jax.random.normal(jax.random.PRNGKey(sum(o_shape)), o_shape,
                           jnp.float32)
-    if kops.conv_detect_sums(o, interpret=interpret) is None:
-        # degenerate flattened view: the kernel route cannot run at all
-        return KernelProfile(False, None,
-                             _time_call(jax.jit(C.detect_sums), o,
-                                        iters=iters), float("inf"))
     f_plain = jax.jit(C.detect_sums)
     f_fused = jax.jit(lambda o: kops.conv_detect_sums(o,
                                                       interpret=interpret))

@@ -25,6 +25,7 @@ from . import checksums as C
 from . import schemes as S
 from . import thresholds as TH
 from . import types as T
+from .types import PRECISION, op_operand, op_operand_dtype, op_output
 from .workflow import run_ladder
 
 F32 = jnp.float32
@@ -51,13 +52,22 @@ def _replicate_small(x: jnp.ndarray) -> jnp.ndarray:
     side of the invariant (observed as c == 2*s on CPU SPMD, a guaranteed
     false positive on clean traffic). The arrays are O(chunks * K);
     replicating them costs one tiny collective and keeps both sides of
-    every comparison in a single layout. No-op when no mesh is in scope.
+    every comparison in a single layout. No-op only when no mesh is in
+    scope (`jax.set_mesh`); any other error propagates, since a dropped
+    constraint would turn into clean-traffic false positives.
     """
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, jax.sharding.PartitionSpec(*([None] * x.ndim)))
-    except Exception:
+    if jax.sharding.get_abstract_mesh().empty:
         return x
+    return jax.lax.with_sharding_constraint(
+        x, jax.sharding.PartitionSpec(*([None] * x.ndim)))
+
+
+def op_matmul(d2: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """The protected GEMM itself: `op_operand_dtype` operands,
+    f32-accumulated at PRECISION."""
+    dt = op_operand_dtype(d2.dtype)
+    return jnp.dot(d2.astype(dt), w.astype(dt), precision=PRECISION,
+                   preferred_element_type=F32)
 
 
 def pick_chunk(n: int, target: int) -> int:
@@ -155,15 +165,18 @@ def _ladder_rungs(cfg: T.ProtectConfig, run_scheme):
     CHECKSUM_REFRESH rung is the Fig. 3 shortcut: fresh checksums inside
     the verifier decide whether O was clean all along."""
     rungs = [
-        (T.CHECKSUM_REFRESH, lambda o: (o, jnp.array(True))),
-        (T.COC, lambda o: run_scheme(S.coc_correct, o, "scalar")),
+        (T.CHECKSUM_REFRESH, lambda o, cs: (o, jnp.array(True))),
+        (T.COC, lambda o, cs: run_scheme(S.coc_correct, o, "scalar", cs)),
     ]
     if cfg.rc_enabled:
-        rungs.append((T.RC, lambda o: run_scheme(S.rc_correct, o, "col")))
+        rungs.append((T.RC,
+                      lambda o, cs: run_scheme(S.rc_correct, o, "col", cs)))
     if cfg.clc_enabled:
-        rungs.append((T.CLC, lambda o: run_scheme(S.clc_correct, o, "row")))
+        rungs.append((T.CLC,
+                      lambda o, cs: run_scheme(S.clc_correct, o, "row", cs)))
     if cfg.fc_enabled:
-        rungs.append((T.FC, lambda o: run_scheme(S.fc_correct, o, "fc")))
+        rungs.append((T.FC,
+                      lambda o, cs: run_scheme(S.fc_correct, o, "fc", cs)))
     return rungs
 
 
@@ -186,9 +199,10 @@ def weight_checksums_matmul(w: jnp.ndarray, col_chunk: int) -> WeightChecksums:
     k, m = w.shape
     cb = pick_chunk(m, col_chunk)
     mb = m // cb
-    w32 = w.astype(F32).reshape(k, mb, cb)
+    w32 = op_operand(w).reshape(k, mb, cb)
     cw1 = jnp.einsum("kbc->bk", w32)
-    cw2 = jnp.einsum("kbc,c->bk", w32, jnp.arange(cb, dtype=F32))
+    cw2 = jnp.einsum("kbc,c->bk", w32, jnp.arange(cb, dtype=F32),
+                     precision=PRECISION)
     return WeightChecksums(cw1, cw2, cb)
 
 
@@ -207,9 +221,10 @@ class _ChunkedChecksums(NamedTuple):
 def _encode_d_chunked(d2: jnp.ndarray, rb: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     n, k = d2.shape
     nb = n // rb
-    d32 = d2.astype(F32).reshape(nb, rb, k)
+    d32 = op_operand(d2).reshape(nb, rb, k)
     cd1 = jnp.sum(d32, axis=1)
-    cd2 = jnp.einsum("brk,r->bk", d32, jnp.arange(rb, dtype=F32))
+    cd2 = jnp.einsum("brk,r->bk", d32, jnp.arange(rb, dtype=F32),
+                     precision=PRECISION)
     return cd1, cd2
 
 
@@ -224,7 +239,7 @@ def _scalar_checksums(cd1, cd2, wck: WeightChecksums) -> _ChunkedChecksums:
     cw1, cw2 = _replicate_small(wck.cw1), _replicate_small(wck.cw2)
     lhs = jnp.concatenate([cd1, cd2, jnp.abs(cd1)], axis=0)
     rhs = jnp.concatenate([cw1, cw2, jnp.abs(cw1)], axis=0)
-    out = _replicate_small(lhs @ rhs.T)
+    out = _replicate_small(jnp.matmul(lhs, rhs.T, precision=PRECISION))
     c5 = out[:nb, :mb]
     c6 = out[nb:2 * nb, :mb]
     c7 = out[:nb, mb:2 * mb]
@@ -250,7 +265,7 @@ def _chunk_sums(o: jnp.ndarray, rb: int, cb: int):
     enc = jnp.stack([jnp.ones((rb * cb,), F32),
                      jnp.repeat(jnp.arange(rb, dtype=F32), cb),
                      jnp.tile(jnp.arange(cb, dtype=F32), rb)])
-    s = _replicate_small(x @ enc.T)
+    s = _replicate_small(jnp.matmul(x, enc.T, precision=PRECISION))
     sumsq = _replicate_small(jnp.sum(x * x, axis=1))
     return (s[:, 0].reshape(nb, mb), s[:, 1].reshape(nb, mb),
             s[:, 2].reshape(nb, mb), sumsq.reshape(nb, mb))
@@ -268,7 +283,8 @@ def _bias_adjust(bias: jnp.ndarray, cb: int) -> BiasAdjust:
     mb = bias.shape[0] // cb
     b = bias.astype(F32).reshape(mb, cb)
     return BiasAdjust(jnp.sum(b, axis=1),
-                      b @ jnp.arange(cb, dtype=F32), b)
+                      jnp.matmul(b, jnp.arange(cb, dtype=F32),
+                                 precision=PRECISION), b)
 
 
 # --------------------------------------------------------------------------
@@ -315,7 +331,7 @@ def protect_matmul_output(
         wck = weight_checksums_matmul(w, cb)
     if recompute_fn is None:
         def recompute_fn():
-            fresh = jnp.dot(d2, w, preferred_element_type=F32)
+            fresh = op_matmul(d2, w)
             if bias is not None:
                 fresh = fresh + bias.astype(F32)
             return fresh.astype(o.dtype)
@@ -370,8 +386,8 @@ def protect_matmul_output(
             jnp.asarray(detected).astype(jnp.bool_).reshape(())
 
     # ---------------- correction ladder (lax.cond branch) ----------------
-    w32 = w.astype(F32)
-    d32 = d2.astype(F32)
+    w32 = op_operand(w)
+    d32 = op_operand(d2)
 
     def _chunk_view(o):
         # (nb, mb, rb, cb, P=1) chunk-major view for the vmapped schemes
@@ -381,32 +397,39 @@ def protect_matmul_output(
     def _unchunk(oc):
         return oc[..., 0].transpose(0, 2, 1, 3).reshape(n, m)
 
-    def _fresh_cs(o):
-        """Trusted checksums + sums for verification (recomputed)."""
+    def _trusted():
+        """The correction branch's checksum sets, derived once: the
+        rungs' (detection-time) set with and without the row/column
+        GEMVs, and freshly recomputed ones for verification."""
         cd1f, cd2f = _encode_d_chunked(d2, rb)
         csf = _scalar_checksums(cd1f, cd2f, wck)
-        return csf
+        return {"fresh": csf,
+                "verify": _chunk_cs_pytree(csf, need_rowcol=True),
+                "scalar": _chunk_cs_pytree(cs, need_rowcol=False),
+                "rowcol": _chunk_cs_pytree(cs, need_rowcol=True)}
 
-    def _verify(o):
-        csf = _fresh_cs(o)
+    def _verify(o, trusted):
+        csf, csp = trusted["fresh"], trusted["verify"]
         # one pass over O: the chunked view's sums carry the scalar
         # invariants too (unused s3/s4 are dead-code-eliminated by XLA)
         ssf = _chunk_ss(o)
         t5 = TH.tau_scalar(ssf.sumsq, k, o.dtype, cfg.tau_factor,
                            csf.absdot)
-        csp = _chunk_cs_pytree(csf, need_rowcol=True)
         return _verify_invariants(csp, ssf, t5[..., None],
                                   t5[..., None, None], rb, cb)
 
     def _rowcol_checksums(cs):
         """c1..c4 for the RC/ClC/FC rungs (the expensive GEMVs; only paid
         inside the correction branch)."""
-        c1 = (cs.cd1 @ w32).reshape(nb, 1, mb, cb).transpose(0, 2, 3, 1)
-        c3 = (cs.cd2 @ w32).reshape(nb, 1, mb, cb).transpose(0, 2, 3, 1)
+        mm = partial(jnp.matmul, precision=PRECISION)
+        c1 = mm(cs.cd1, w32).reshape(nb, 1, mb, cb).transpose(0, 2, 3, 1)
+        c3 = mm(cs.cd2, w32).reshape(nb, 1, mb, cb).transpose(0, 2, 3, 1)
         # (nb, mb, rb, 1): D-chunk @ per-chunk weight checksums
         d3 = d32.reshape(nb, rb, k)
-        c2 = jnp.einsum("brk,mk->bmr", d3, cs.cw1)[..., None]
-        c4 = jnp.einsum("brk,mk->bmr", d3, cs.cw2)[..., None]
+        c2 = jnp.einsum("brk,mk->bmr", d3, cs.cw1,
+                        precision=PRECISION)[..., None]
+        c4 = jnp.einsum("brk,mk->bmr", d3, cs.cw2,
+                        precision=PRECISION)[..., None]
         if adj is not None:
             sum_n = rb * (rb - 1) / 2.0
             c1 = c1 + rb * adj.b_chunks[None, :, :, None]
@@ -435,19 +458,20 @@ def protect_matmul_output(
         o32 = oc.astype(F32)
         s1 = jnp.sum(o32, axis=2)[..., 0][..., None]          # (nb,mb,cb,1)
         s2 = jnp.sum(o32, axis=3)[..., 0][..., None]          # (nb,mb,rb,1)
-        s3 = jnp.einsum("abrcp,r->abcp", o32, wn)
-        s4 = jnp.einsum("abrcp,c->abrp", o32, wm)
-        s5 = jnp.einsum("abcp->abp", s1)
-        s6 = jnp.einsum("abrp,r->abp", s2, wn)
-        s7 = jnp.einsum("abcp,c->abp", s1, wm)
-        sq = jnp.einsum("abrcp,abrcp->ab", o32, o32)
+        ein = partial(jnp.einsum, precision=PRECISION)
+        s3 = ein("abrcp,r->abcp", o32, wn)
+        s4 = ein("abrcp,c->abrp", o32, wm)
+        s5 = ein("abcp->abp", s1)
+        s6 = ein("abrp,r->abp", s2, wn)
+        s7 = ein("abcp,c->abp", s1, wm)
+        sq = ein("abrcp,abrcp->ab", o32, o32)
         return T.OutputSums(s1, s2, s3, s4, s5, s6, s7, sq)
 
     vmap2 = lambda f: jax.vmap(jax.vmap(f))
 
-    def _run_scheme(scheme_fn, o, tau_kind):
+    def _run_scheme(scheme_fn, o, tau_kind, trusted):
         oc = _chunk_view(o)
-        cs_c = _chunk_cs_pytree(cs, need_rowcol=tau_kind != "scalar")
+        cs_c = trusted["scalar" if tau_kind == "scalar" else "rowcol"]
         ss_c = _chunk_ss(o)
         t5 = TH.tau_scalar(ss_c.sumsq, k, o.dtype, cfg.tau_factor, cs.absdot)
         taus = _scheme_taus(tau_kind, t5[..., None], t5[..., None, None],
@@ -456,7 +480,7 @@ def protect_matmul_output(
         return _unchunk(fixed), jnp.all(ok)
 
     rungs = _ladder_rungs(cfg, _run_scheme)
-    return run_ladder(o, detected, rungs, _verify, recompute_fn)
+    return run_ladder(o, detected, rungs, _verify, recompute_fn, _trusted)
 
 
 def protected_matmul(
@@ -479,9 +503,9 @@ def protected_matmul(
     m = w.shape[-1]
     d2 = d.reshape(-1, k)
     if cfg is None or not cfg.enabled:
-        o = jnp.dot(d2, w, preferred_element_type=F32).astype(d.dtype)
+        o = op_output(op_matmul(d2, w), d.dtype)
         if bias is not None:
-            o = o + bias.astype(o.dtype)
+            o = op_output(o.astype(F32) + bias.astype(F32), o.dtype)
         return _clean_result(o.reshape(*lead, m), mode)
 
     if cfg.use_fused_kernel:
@@ -507,26 +531,25 @@ def protected_matmul(
             tau_a, tau_b = TH.tau_scalar_coeffs(k, d.dtype, cfg.tau_factor)
             res = kops.abft_matmul_detect(
                 d2, w, cs.c5, cs.c6, cs.c7, cs.absdot, rb=rb, cb=cb,
-                bk=(cfg.kernel_tiles or (0, 0, 256))[2], tau_a=tau_a,
+                bk=(cfg.kernel_tiles or (0, 0, 512))[2], tau_a=tau_a,
                 tau_b=tau_b, weighted=cfg.detect_weighted,
                 interpret=cfg.resolve_interpret())
             if res is not None:
                 o, flag, score = res
                 return (o.reshape(*lead, m),
                         T.DetectEvidence(jnp.max(flag), jnp.max(score)))
-        # plan-pinned tiles when profiled, else shape-derived defaults that
-        # divide the checksum chunks so partials recombine exactly; a
-        # non-dividing pinned tile recombines from O instead (ops.py)
-        bm, bn, bk = cfg.kernel_tiles or (kops._tile(rb, 256),
-                                          kops._tile(cb, 256), 256)
+        # plan-pinned tile targets when profiled, else the kernel's
+        # defaults; a tile that does not divide the checksum chunks
+        # recombines from O instead (ops.chunk_sums_from_partials)
+        bm, bn, bk = cfg.kernel_tiles or (256, 256, 512)
         o, parts = kops.abft_matmul(
             d2, w, interpret=cfg.resolve_interpret(), bm=bm, bn=bn, bk=bk)
         pre = kops.chunk_sums_from_partials(parts, rb, cb, o=o)
     else:
-        o = jnp.dot(d2, w, preferred_element_type=F32).astype(d.dtype)
+        o = op_output(op_matmul(d2, w), d.dtype)
         pre = None
     if bias is not None:
-        o = (o.astype(F32) + bias.astype(F32)).astype(o.dtype)
+        o = op_output(o.astype(F32) + bias.astype(F32), o.dtype)
     o, rep = protect_matmul_output(d2, w, o, wck=wck, bias=bias, cfg=cfg,
                                    precomputed_sums=pre, mode=mode,
                                    detected=detected)
@@ -561,8 +584,8 @@ def _bwd(cfg, res, g):
         dd2, _ = protected_matmul(g2, w.T.astype(g2.dtype), cfg=cfg)
         dw, _ = protected_matmul(d2.T, g2.astype(d2.dtype), cfg=cfg)
     else:
-        dd2 = jnp.dot(g2, w.T.astype(g2.dtype), preferred_element_type=F32)
-        dw = jnp.dot(d2.T, g2.astype(d2.dtype), preferred_element_type=F32)
+        dd2 = op_matmul(g2, w.T.astype(g2.dtype))
+        dw = op_matmul(d2.T, g2.astype(d2.dtype))
     return dd2.reshape(*lead, k).astype(d.dtype), dw.astype(w.dtype)
 
 
@@ -633,10 +656,11 @@ def protected_conv(
             None if cs.c1 is None else cs.c1 + n_ * b[:, None],
             None if cs.c2 is None else cs.c2 + jnp.sum(b),
             None if cs.c3 is None else cs.c3 + sum_n * b[:, None],
-            None if cs.c4 is None else cs.c4 + jnp.dot(wm, b),
+            None if cs.c4 is None
+            else cs.c4 + jnp.dot(wm, b, precision=PRECISION),
             cs.c5 + n_ * jnp.sum(b),
             cs.c6 + sum_n * jnp.sum(b),
-            cs.c7 + n_ * jnp.dot(wm, b),
+            cs.c7 + n_ * jnp.dot(wm, b, precision=PRECISION),
         )
 
     def _cs(need_rowcol):
@@ -699,23 +723,30 @@ def protected_conv(
     def _denorm(o3):
         return o3.reshape(o.shape)
 
-    def _verify(oo):
+    def _trusted():
+        """The correction branch's checksum sets, derived once (the c1-c4
+        checksum convs are the bulk of every rung): the rungs' set, and
+        for verification the same set - or, when the detection-path set
+        was tampered with (test hook), a clean re-encode."""
+        cs = _cs(need_rowcol=True)
+        if tamper_checksums is None:
+            return cs, cs
+        return cs, _bias_adjusted(C.output_checksums_conv(
+            d, w, *C.encode_d_conv(d), *C.encode_w_conv(w, groups=groups),
+            stride=stride, padding=padding, groups=groups,
+            need_rowcol=True))
+
+    def _verify(oo, trusted):
         ssv = C.output_sums_conv(oo)
-        # verification must use trusted checksums: re-encode when the
-        # detection-path set was tampered with (test hook)
-        csf = _cs(need_rowcol=True) if tamper_checksums is None else \
-            _bias_adjusted(C.output_checksums_conv(
-                d, w, *C.encode_d_conv(d), *C.encode_w_conv(w, groups=groups),
-                stride=stride, padding=padding, groups=groups,
-                need_rowcol=True))
+        csf = trusted[1]
         t5 = TH.tau_scalar(ssv.sumsq * jnp.ones(()), k_eq, oo.dtype,
                            cfg.tau_factor, absd)
         t5 = jnp.broadcast_to(t5, (p,))
         return _verify_invariants(csf, ssv, t5, t5[None, :], n_, m_)
 
-    def _run_scheme(fn, oo, tau_kind):
+    def _run_scheme(fn, oo, tau_kind, trusted):
         o3 = _norm(oo)
-        cs = _cs(need_rowcol=True)
+        cs = trusted[0]
         ss = C.output_sums_conv(oo)
         t5 = TH.tau_scalar(ss.sumsq * jnp.ones(()), k_eq, oo.dtype,
                            cfg.tau_factor, absd)
@@ -725,7 +756,7 @@ def protected_conv(
         return _denorm(fixed), ok
 
     rungs = _ladder_rungs(cfg, _run_scheme)
-    return run_ladder(o, detected, rungs, _verify, recompute_fn)
+    return run_ladder(o, detected, rungs, _verify, recompute_fn, _trusted)
 
 
 # --------------------------------------------------------------------------
@@ -747,7 +778,9 @@ def protected_grouped_matmul(
     detect-only mode the evidence carry is the max over groups (any
     flagged expert flags the op)."""
     if cfg is None or not cfg.enabled:
-        o = jnp.einsum("gnk,gkm->gnm", d, w,
+        dt = op_operand_dtype(d.dtype)
+        o = jnp.einsum("gnk,gkm->gnm", d.astype(dt), w.astype(dt),
+                       precision=PRECISION,
                        preferred_element_type=F32).astype(d.dtype)
         return _clean_result(o, mode)
 

@@ -13,6 +13,57 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# The arithmetic of the protected path, stated once. The protected op
+# multiplies `op_operand_dtype` operands - on a TPU, f32 operands go to the
+# MXU as bf16, which is what XLA's default precision does there, made
+# explicit - with exact products and f32 accumulation. The checksum side
+# encodes those same operand values (`op_operand`) and runs every product
+# at PRECISION (HIGHEST: f32-accurate), so an op and its checksum differ by
+# f32 accumulation order only and the eps_f32 noise model of
+# core/thresholds.py holds unchanged. Left implicit, the op's ~2^-8 operand
+# rounding would sit far above tau and flag clean traffic at every layer.
+PRECISION = jax.lax.Precision.HIGHEST
+
+
+
+def op_operand_dtype(dtype):
+    """The dtype the protected op multiplies for operands of `dtype`."""
+    dtype = jnp.dtype(dtype)
+    if dtype == jnp.float32 and jax.default_backend() == "tpu":
+        return jnp.dtype(jnp.bfloat16)
+    return dtype
+
+
+def round_to(x: jnp.ndarray, dtype) -> jnp.ndarray:
+    """`x` rounded to the precision of float `dtype`, kept in f32.
+
+    A `reduce_precision`, not an astype round trip: under XLA's default
+    excess precision a fused f32 -> bf16 -> f32 pair may be computed
+    without rounding (seen on a v5e: the deferred resnet18's fc checksum
+    encoded unrounded activations and flagged clean traffic), while
+    reduce_precision always rounds."""
+    x32 = x.astype(jnp.float32)
+    dtype = jnp.dtype(dtype)
+    if not jnp.issubdtype(dtype, jnp.floating) or dtype.itemsize >= 4:
+        return x32
+    fi = jnp.finfo(dtype)
+    return jax.lax.reduce_precision(x32, exponent_bits=fi.nexp,
+                                    mantissa_bits=fi.nmant)
+
+
+def op_operand(x: jnp.ndarray) -> jnp.ndarray:
+    """The values the protected op multiplies for operand `x`, in f32 -
+    what every checksum encode starts from."""
+    return round_to(x, op_operand_dtype(x.dtype))
+
+
+def op_output(o32: jnp.ndarray, dtype) -> jnp.ndarray:
+    """The op's f32 product as a `dtype` output whose rounding no fusion
+    can skip: the protected program, whose checksums also read the
+    output, and the unprotected one then see the same values."""
+    return round_to(o32, dtype).astype(dtype)
+
+
 # corrected_by enum (kept as plain ints so they live inside jit).
 NONE = 0          # no fault detected
 COC = 1           # corrected by checksum-of-checksums
@@ -291,10 +342,7 @@ class ProtectConfig:
 
 def default_kernel_interpret() -> bool:
     """Interpret Pallas kernels everywhere but TPU (where they compile)."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # pragma: no cover - backend probing never raises today
-        return True
+    return jax.default_backend() != "tpu"
 
 
 DEFAULT_CONFIG = ProtectConfig()

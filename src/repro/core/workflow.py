@@ -12,28 +12,35 @@ disabled rungs are not even traced.
 """
 from __future__ import annotations
 
-from typing import Callable, List, Tuple
+from typing import Any, Callable, List, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from . import types as T
 
-# A rung: o -> (o_fixed, ok). Verification is applied by the engine.
-Rung = Tuple[int, Callable[[jnp.ndarray], Tuple[jnp.ndarray, jnp.ndarray]]]
+# A rung: (o, trusted) -> (o_fixed, ok). Verification is applied by the
+# engine.
+Rung = Tuple[int, Callable[[jnp.ndarray, Any],
+                           Tuple[jnp.ndarray, jnp.ndarray]]]
 
 
 def run_ladder(
     o: jnp.ndarray,
     detected: jnp.ndarray,
     rungs: List[Rung],
-    verify_fn: Callable[[jnp.ndarray], jnp.ndarray],
+    verify_fn: Callable[[jnp.ndarray, Any], jnp.ndarray],
     recompute_fn: Callable[[], jnp.ndarray],
+    trusted_fn: Callable[[], Any],
 ) -> Tuple[jnp.ndarray, T.FaultReport]:
     """Escalate through `rungs` until one verifies; fall back to recompute.
 
-    verify_fn(o) must re-derive the output summations of `o` and compare
-    against trusted (freshly recomputed) checksums - returning a scalar bool.
+    trusted_fn() derives the checksum sets once, at the top of the
+    correction branch; every rung and every verification reads them
+    instead of re-encoding (they depend on D and W only, not on the
+    candidate output). verify_fn(o, trusted) must re-derive the output
+    summations of `o` and compare against the trusted checksums -
+    returning a scalar bool.
     """
 
     def _clean(o):
@@ -42,14 +49,15 @@ def run_ladder(
 
     def _correct(o):
         by = jnp.zeros((), jnp.int32)
+        trusted = trusted_fn()
 
         for enum_val, fn in rungs:
             # apply rung only while uncorrected; lax.cond keeps the rung's
             # cost out of the path once a lower rung succeeded.
             def _attempt(args, fn=fn, enum_val=enum_val):
                 o, by = args
-                fixed, ok = fn(o)
-                ok = ok & verify_fn(fixed)
+                fixed, ok = fn(o, trusted)
+                ok = ok & verify_fn(fixed, trusted)
                 o = jnp.where(ok, fixed, o)
                 by = jnp.where(ok, jnp.int32(enum_val), by)
                 return o, by
@@ -66,7 +74,7 @@ def run_ladder(
             return fresh, jnp.int32(T.RECOMPUTE)
 
         o, by = jax.lax.cond(by == 0, _recompute, _skip, (o, by))
-        residual = jnp.where(verify_fn(o), 0, 1).astype(jnp.int32)
+        residual = jnp.where(verify_fn(o, trusted), 0, 1).astype(jnp.int32)
         return o, by, residual
 
     o, by, residual = jax.lax.cond(detected, _correct, _clean, o)

@@ -4,24 +4,30 @@ into the GEMM epilogue.
 The paper's runtime model (Table 3/4) charges a beta-weighted *extra pass*
 over O to encode the output summations (S_o). On TPU that pass is a second
 HBM round-trip of the largest tensor in the op. Here the per-tile partial
-row/column sums and the sum-of-squares (threshold scale) are computed while
-the accumulator tile is still in VMEM and written as tiny partials:
+sums are computed while the accumulator tile is still in VMEM and written
+as one small lane-dense block per tile:
 
-    colsum : (N/bm, M)   per-row-tile column sums   -> S_o1/S_o5/S_o7
-    rowsum : (N, M/bn)   per-col-tile row sums      -> S_o2/S_o6
-    sumsq  : (N/bm, M/bn) per-tile sum of squares   -> detection threshold
+    sums : (N/bm, 3, M)   rows per row-tile: column sum, locally
+                          row-index-weighted column sum, column sum of
+                          squares -> S_o5/S_o6/S_o7 and the threshold scale
 
 A negligible jnp reduction (repro.kernels.ops.chunk_sums_from_partials)
-finishes them at any chunk granularity that is a multiple of the tile. The
-index-weighted invariants need no extra kernel outputs: full column (row)
-resolution of colsum (rowsum) lets the wrapper apply local index weights
-exactly.
+finishes them at any chunk granularity that is a multiple of the tile.
+Full column resolution lets the wrapper apply the column-index weights of
+s7 exactly; the locally row-weighted sum plus each tile's row offset gives
+the row-index weights of s6.
 
-MXU alignment: tiles default to 256x256x256 (fp32 grid multiples of the
-128x128 systolic array); the fp32 accumulator lives in VMEM scratch.
-VMEM working set at defaults: D-tile + W-tile + O-tile + acc
-= 4 * 256*256*4B = 1 MiB, well under the ~16 MiB/core budget, leaving
-room for double buffering of the streamed D/W tiles.
+Mosaic layout rules shape every block here: the last two dimensions of a
+block are multiples of (8, 128) - (16, 128) for 16-bit operands - or equal
+to the array's own. Per-tile results therefore leave the kernel as
+(3, bn) / (2, 128) rows of 3-D arrays whose middle axis is exactly that
+wide; ops.py picks tiles that satisfy the rule (padding where it must).
+
+MXU alignment: tiles default to 256x256 output blocks; the fp32
+accumulator lives in VMEM scratch. Tiles multiply the protected op's
+operand values (core/types.op_operand_dtype, passed in as `operand_dtype`)
+with exact products and f32 accumulation, the arithmetic the checksum
+side encodes.
 """
 from __future__ import annotations
 
@@ -31,214 +37,206 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU compiler params are versioned; interpret mode needs none
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from repro.core.types import PRECISION
 
 F32 = jnp.float32
+LANES = 128      # per-tile verdicts are broadcast across one lane row
 
 
-def _acc_scratch(bm: int, bn: int):
-    """fp32 accumulator scratch spec. pltpu.VMEM pins it to VMEM on TPU;
-    when the pallas.tpu import failed (non-TPU jaxlib builds), interpret
-    mode - the documented fallback for exactly that situation - must not
-    dereference the absent module, so it gets the backend-agnostic
-    MemoryRef instead."""
-    if pltpu is not None:
-        return pltpu.VMEM((bm, bn), F32)
-    return pl.MemoryRef((bm, bn), F32, pl.ANY)
+def _dot(a, b, operand_dtype):
+    """Tile product in the protected op's arithmetic (protected.op_matmul):
+    operands rounded to `operand_dtype`, f32 accumulation. Mosaic takes a
+    precision only for f32 operands; narrower ones multiply exactly."""
+    f32 = jnp.dtype(operand_dtype) == F32
+    return jnp.dot(a.astype(operand_dtype), b.astype(operand_dtype),
+                   preferred_element_type=F32,
+                   precision=PRECISION if f32 else None)
 
 
-def _kernel(d_ref, w_ref, o_ref, colsum_ref, rowsum_ref, sumsq_ref,
-            acc_ref, *, k_steps: int):
-    k = pl.program_id(2)
+def _row_iota(shape) -> jnp.ndarray:
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 0).astype(F32)
 
-    @pl.when(k == 0)
+
+def _col_iota(shape) -> jnp.ndarray:
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1).astype(F32)
+
+
+def tile_sums(tile: jnp.ndarray, sums_ref) -> None:
+    """Store the (3, bn) partials of one f32 tile: column sum, locally
+    row-index-weighted column sum, column sum of squares. Shared with
+    checksum_reduce, so both kernels emit one partials layout."""
+    sums_ref[0:1, :] = jnp.sum(tile, axis=0, keepdims=True)
+    sums_ref[1:2, :] = jnp.sum(tile * _row_iota(tile.shape), axis=0,
+                               keepdims=True)
+    sums_ref[2:3, :] = jnp.sum(tile * tile, axis=0, keepdims=True)
+
+
+def _params(n_parallel: int, reduce_axis: bool = True):
+    sem = ("parallel",) * n_parallel + (("arbitrary",) if reduce_axis
+                                        else ())
+    return pltpu.CompilerParams(dimension_semantics=sem)
+
+
+def _accumulate(d_ref, w_ref, acc_ref, operand_dtype):
+    @pl.when(pl.program_id(2) == 0)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(d_ref[...].astype(F32), w_ref[...].astype(F32),
-                            preferred_element_type=F32)
+    acc_ref[...] += _dot(d_ref[...], w_ref[...], operand_dtype)
 
-    @pl.when(k == k_steps - 1)
+
+def _kernel(d_ref, w_ref, o_ref, sums_ref, acc_ref, *, k_steps: int,
+            operand_dtype):
+    _accumulate(d_ref, w_ref, acc_ref, operand_dtype)
+
+    @pl.when(pl.program_id(2) == k_steps - 1)
     def _epilogue():
         acc = acc_ref[...]
         o_ref[...] = acc.astype(o_ref.dtype)
-        # checksum epilogue: tile is in VMEM - the extra HBM traffic is
-        # (M + N*M/bn + N*M/bm) fp32 words instead of a full re-read of O.
-        colsum_ref[...] = jnp.sum(acc, axis=0, keepdims=True)
-        rowsum_ref[...] = jnp.sum(acc, axis=1, keepdims=True)
-        sumsq_ref[...] = jnp.sum(acc * acc).reshape(1, 1)
+        # checksum epilogue: the tile is in VMEM - the extra HBM traffic
+        # is 3*M*N/bm fp32 words instead of a full re-read of O
+        tile_sums(acc, sums_ref)
+
+
+def _gemm_specs(bm: int, bn: int, bk: int):
+    return [pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j))]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret",
-                                             "out_dtype"))
-def abft_matmul(d: jnp.ndarray, w: jnp.ndarray, *, bm: int = 256,
-                bn: int = 256, bk: int = 256, interpret: bool = True,
-                out_dtype=None) -> Tuple[jnp.ndarray, Tuple]:
-    """Returns (O, (colsum, rowsum, sumsq)). Shapes must tile evenly; the
-    ops.py wrapper falls back to the jnp reference otherwise."""
+                                             "out_dtype", "operand_dtype"))
+def abft_matmul(d: jnp.ndarray, w: jnp.ndarray, *, bm: int, bn: int,
+                bk: int, interpret: bool, operand_dtype,
+                out_dtype=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Returns (O, sums (N/bm, 3, M)). Shapes must tile evenly and the
+    tiles must be legal Mosaic blocks; ops.abft_matmul picks and pads."""
     n, k = d.shape
     k2, m = w.shape
     assert k == k2, (d.shape, w.shape)
-    bm, bn, bk = min(bm, n), min(bn, m), min(bk, k)
     assert n % bm == 0 and m % bn == 0 and k % bk == 0, (
         f"abft_matmul needs tile-aligned shapes, got {(n, k, m)} with "
         f"tiles {(bm, bk, bn)}")
     out_dtype = out_dtype or d.dtype
     grid = (n // bm, m // bn, k // bk)
-
-    kernel = functools.partial(_kernel, k_steps=grid[2])
-    kwargs = {}
-    if not interpret and pltpu is not None:  # pragma: no cover (TPU only)
-        params = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams")
-        kwargs["compiler_params"] = params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-    o, colsum, rowsum, sumsq = pl.pallas_call(
-        kernel,
+    o, sums = pl.pallas_call(
+        functools.partial(_kernel, k_steps=grid[2],
+                          operand_dtype=operand_dtype),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-        ],
+        in_specs=_gemm_specs(bm, bn, bk),
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (i, j)),
-            pl.BlockSpec((bm, 1), lambda i, j, kk: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j, kk: (i, j)),
+            pl.BlockSpec((None, 3, bn), lambda i, j, kk: (i, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, m), out_dtype),
-            jax.ShapeDtypeStruct((n // bm, m), F32),
-            jax.ShapeDtypeStruct((n, m // bn), F32),
-            jax.ShapeDtypeStruct((n // bm, m // bn), F32),
+            jax.ShapeDtypeStruct((grid[0], 3, m), F32),
         ],
-        scratch_shapes=[_acc_scratch(bm, bn)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), F32)],
+        compiler_params=_params(2),
         interpret=interpret,
-        **kwargs,
     )(d, w)
-    return o, (colsum, rowsum, sumsq, bm, bn)
+    return o, sums
 
 
 # --------------------------------------------------------------------------
 # fused GEMM + in-epilogue threshold compare (single-launch detection)
 # --------------------------------------------------------------------------
 
-def _detect_kernel(d_ref, w_ref, c5_ref, c6_ref, c7_ref, absdot_ref,
-                   o_ref, flag_ref, score_ref, acc_ref, *, k_steps: int,
-                   tau_a: float, tau_b: float, weighted: bool):
+def _total(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum of a 2-D tile as a (1, 1) array (vector reductions only)."""
+    return jnp.sum(jnp.sum(x, axis=0, keepdims=True), axis=1, keepdims=True)
+
+
+def _detect_kernel(d_ref, w_ref, cs_ref, o_ref, verdict_ref, acc_ref, *,
+                   k_steps: int, tau_a: float, tau_b: float,
+                   weighted: bool, operand_dtype):
     """abft_matmul's epilogue extended with the CoC-D compare itself: the
     per-tile scalar invariants (s5 and, when `weighted`, the locally
     index-weighted s6/s7) are reduced from the VMEM accumulator and
     compared against the checksum-side predictions while the tile is
-    still resident - one scalar flag (+ evidence score) per tile leaves
-    the kernel instead of the O(N+M)-sized summation partials.
+    still resident - one flag (+ evidence score) per tile leaves the
+    kernel instead of the summation partials.
 
-    tau inlines thresholds.tau_scalar's affine form (tau_scalar_coeffs):
+    cs_ref is the tile's (4, LANES) block of checksum predictions: rows
+    c5, c6, c7, absdot, each broadcast across the lanes. The verdict
+    block is (2, LANES): row 0 the flag (0/1), row 1 the score. tau
+    inlines thresholds.tau_scalar's affine form (tau_scalar_coeffs):
     tau5 = tau_a*sqrt(sumsq) + tau_b*absdot + 1e-30, with the weighted
     invariants amplified by the tile extents (tau_weighted). NaN/Inf on
     either side of a compare flags the tile (mismatch semantics)."""
-    k = pl.program_id(2)
+    _accumulate(d_ref, w_ref, acc_ref, operand_dtype)
 
-    @pl.when(k == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += jnp.dot(d_ref[...].astype(F32), w_ref[...].astype(F32),
-                            preferred_element_type=F32)
-
-    @pl.when(k == k_steps - 1)
+    @pl.when(pl.program_id(2) == k_steps - 1)
     def _epilogue():
         acc = acc_ref[...]
         o_ref[...] = acc.astype(o_ref.dtype)
         bm, bn = acc.shape
-        sumsq = jnp.sum(acc * acc)
-        tau5 = (tau_a * jnp.sqrt(jnp.maximum(sumsq, 0.0))
-                + tau_b * absdot_ref[0, 0] + 1e-30)
-        cs = [(c5_ref[0, 0], jnp.sum(acc), tau5)]
+        cs = cs_ref[...]
+        tau5 = (tau_a * jnp.sqrt(jnp.maximum(_total(acc * acc), 0.0))
+                + tau_b * cs[3:4, :] + 1e-30)
+        pairs = [(cs[0:1, :], _total(acc), tau5)]
         if weighted:
-            wn = jax.lax.broadcasted_iota(F32, acc.shape, 0)
-            wm = jax.lax.broadcasted_iota(F32, acc.shape, 1)
-            cs += [(c6_ref[0, 0], jnp.sum(acc * wn),
-                    tau5 * float(max(bm - 1, 1))),
-                   (c7_ref[0, 0], jnp.sum(acc * wm),
-                    tau5 * float(max(bn - 1, 1)))]
-        flag = jnp.zeros((), jnp.bool_)
-        score = jnp.zeros((), F32)
-        for c, s, t in cs:
-            bad = ~(jnp.isfinite(c) & jnp.isfinite(s))
-            flag |= bad | (jnp.abs(c - s) > t)
-            score = jnp.maximum(score,
-                                jnp.where(bad, jnp.inf, jnp.abs(c - s) / t))
-        flag_ref[0, 0] = flag.astype(jnp.int32)
-        score_ref[0, 0] = score
+            pairs += [(cs[1:2, :], _total(acc * _row_iota(acc.shape)),
+                       tau5 * float(max(bm - 1, 1))),
+                      (cs[2:3, :], _total(acc * _col_iota(acc.shape)),
+                       tau5 * float(max(bn - 1, 1)))]
+        flag = jnp.zeros((1, LANES), jnp.bool_)
+        score = jnp.zeros((1, LANES), F32)
+        for c, s, t in pairs:
+            bad = ~((jnp.abs(c) < jnp.inf) & (jnp.abs(s) < jnp.inf))
+            gap = jnp.abs(c - s)
+            flag = flag | bad | (gap > t)
+            score = jnp.maximum(score, jnp.where(bad, jnp.inf, gap / t))
+        verdict_ref[0:1, :] = flag.astype(F32)
+        verdict_ref[1:2, :] = score
 
 
 @functools.partial(jax.jit, static_argnames=(
     "bm", "bn", "bk", "tau_a", "tau_b", "weighted", "interpret",
-    "out_dtype"))
-def abft_matmul_detect(d: jnp.ndarray, w: jnp.ndarray, c5: jnp.ndarray,
-                       c6: jnp.ndarray, c7: jnp.ndarray,
-                       absdot: jnp.ndarray, *, bm: int, bn: int,
-                       bk: int = 256, tau_a: float, tau_b: float,
-                       weighted: bool = True, interpret: bool = True,
-                       out_dtype=None) -> Tuple[jnp.ndarray, jnp.ndarray,
-                                                jnp.ndarray]:
+    "out_dtype", "operand_dtype"))
+def abft_matmul_detect(d: jnp.ndarray, w: jnp.ndarray, cs: jnp.ndarray, *,
+                       bm: int, bn: int, bk: int, tau_a: float,
+                       tau_b: float, weighted: bool, interpret: bool,
+                       operand_dtype,
+                       out_dtype=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """O = D @ W plus the in-epilogue CoC-D compare: ONE kernel launch
-    returning (O, flag (nb, mb) i32, score (nb, mb) f32).
+    returning (O, verdict (nb, 2, mb*LANES) f32).
 
-    Detection chunk granularity IS the kernel tile here (c5/c6/c7/absdot
-    are the per-(bm x bn)-chunk checksum predictions, locally
-    index-weighted), so the launch subsumes both the GEMM and the whole
-    detection pass - no summation partials leave the kernel and no
-    separate detection dispatch runs. tau_a/tau_b are the static affine
-    threshold coefficients (thresholds.tau_scalar_coeffs)."""
+    Detection chunk granularity IS the kernel tile here: `cs` holds the
+    per-(bm x bn)-chunk checksum predictions (c5, c6, c7, absdot;
+    locally index-weighted) as (nb, 4, mb*LANES), each value broadcast
+    across its tile's lanes (ops.abft_matmul_detect packs and unpacks).
+    tau_a/tau_b are the static affine threshold coefficients
+    (thresholds.tau_scalar_coeffs)."""
     n, k = d.shape
     k2, m = w.shape
     assert k == k2, (d.shape, w.shape)
-    bk = min(bk, k)
     assert n % bm == 0 and m % bn == 0 and k % bk == 0, (
         f"abft_matmul_detect needs tile-aligned shapes, got {(n, k, m)} "
         f"with tiles {(bm, bk, bn)}")
     nb, mb = n // bm, m // bn
-    assert c5.shape == (nb, mb), (c5.shape, (nb, mb))
+    assert cs.shape == (nb, 4, mb * LANES), (cs.shape, (nb, 4, mb * LANES))
     out_dtype = out_dtype or d.dtype
     grid = (nb, mb, k // bk)
-
     kernel = functools.partial(_detect_kernel, k_steps=grid[2],
-                               tau_a=tau_a, tau_b=tau_b, weighted=weighted)
-    kwargs = {}
-    if not interpret and pltpu is not None:  # pragma: no cover (TPU only)
-        params = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams")
-        kwargs["compiler_params"] = params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-    chunk_spec = pl.BlockSpec((1, 1), lambda i, j, kk: (i, j))
-    o, flag, score = pl.pallas_call(
+                               tau_a=tau_a, tau_b=tau_b, weighted=weighted,
+                               operand_dtype=operand_dtype)
+    return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            chunk_spec, chunk_spec, chunk_spec, chunk_spec,
-        ],
+        in_specs=_gemm_specs(bm, bn, bk) + [
+            pl.BlockSpec((None, 4, LANES), lambda i, j, kk: (i, 0, j))],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-            chunk_spec, chunk_spec,
+            pl.BlockSpec((None, 2, LANES), lambda i, j, kk: (i, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, m), out_dtype),
-            jax.ShapeDtypeStruct((nb, mb), jnp.int32),
-            jax.ShapeDtypeStruct((nb, mb), F32),
+            jax.ShapeDtypeStruct((nb, 2, mb * LANES), F32),
         ],
-        scratch_shapes=[_acc_scratch(bm, bn)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), F32)],
+        compiler_params=_params(2),
         interpret=interpret,
-        **kwargs,
-    )(d, w, c5.astype(F32), c6.astype(F32), c7.astype(F32),
-      absdot.astype(F32))
-    return o, flag, score
+    )(d, w, cs.astype(F32))

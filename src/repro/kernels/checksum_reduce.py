@@ -4,8 +4,8 @@ The conv/attention outputs of the paper's workflow need S_o sums even when
 the producing op is not our fused GEMM (XLA conv, attention, an external
 library - "any convolution implementation"). This kernel reads O exactly
 once from HBM and emits the same partials as the fused epilogue
-(colsum/rowsum/sumsq) plus a locally-index-weighted column sum (wcolsum),
-replacing the multiple beta-passes of the paper's encode step.
+(abft_matmul.tile_sums): per row tile, the column sum, the locally
+row-index-weighted column sum (wcolsum) and the column sum of squares.
 
 wcolsum weights each row by its index *within the tile*; combined with the
 tile's base row index it reconstructs any affine row weighting exactly:
@@ -20,62 +20,35 @@ the flattened (N*M, E*E) view without a second pass (kernels.ops
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from .abft_matmul import _params, tile_sums
 
 F32 = jnp.float32
 
 
-def _kernel(o_ref, colsum_ref, rowsum_ref, sumsq_ref, wcolsum_ref):
-    tile = o_ref[...].astype(F32)
-    colsum_ref[...] = jnp.sum(tile, axis=0, keepdims=True)
-    rowsum_ref[...] = jnp.sum(tile, axis=1, keepdims=True)
-    sumsq_ref[...] = jnp.sum(tile * tile).reshape(1, 1)
-    # local row-index weights (2D iota: TPU requires >=2D)
-    w = jax.lax.broadcasted_iota(F32, tile.shape, 0)
-    wcolsum_ref[...] = jnp.sum(w * tile, axis=0, keepdims=True)
+def _kernel(o_ref, sums_ref):
+    tile_sums(o_ref[...].astype(F32), sums_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
-def checksum_reduce(o: jnp.ndarray, *, bm: int = 512, bn: int = 512,
-                    interpret: bool = True) -> Tuple:
-    """Returns (colsum (N/bm, M), rowsum (N, M/bn), sumsq (N/bm, M/bn),
-    wcolsum (N/bm, M), bm, bn)."""
+def checksum_reduce(o: jnp.ndarray, *, bm: int, bn: int,
+                    interpret: bool) -> jnp.ndarray:
+    """Returns sums (N/bm, 3, M): rows colsum, wcolsum, column sum of
+    squares per row tile. Shapes must tile evenly into legal Mosaic
+    blocks; ops.py picks the tiles."""
     n, m = o.shape
-    bm, bn = min(bm, n), min(bn, m)
     assert n % bm == 0 and m % bn == 0, (o.shape, bm, bn)
     grid = (n // bm, m // bn)
-    kwargs = {}
-    if not interpret and pltpu is not None:  # pragma: no cover
-        params = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams")
-        kwargs["compiler_params"] = params(
-            dimension_semantics=("parallel", "parallel"))
-    colsum, rowsum, sumsq, wcolsum = pl.pallas_call(
+    return pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
-        out_specs=[
-            pl.BlockSpec((1, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((1, bn), lambda i, j: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n // bm, m), F32),
-            jax.ShapeDtypeStruct((n, m // bn), F32),
-            jax.ShapeDtypeStruct((n // bm, m // bn), F32),
-            jax.ShapeDtypeStruct((n // bm, m), F32),
-        ],
+        out_specs=pl.BlockSpec((None, 3, bn), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((grid[0], 3, m), F32),
+        compiler_params=_params(2, reduce_axis=False),
         interpret=interpret,
-        **kwargs,
     )(o)
-    return colsum, rowsum, sumsq, wcolsum, bm, bn

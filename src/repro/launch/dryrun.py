@@ -229,10 +229,8 @@ def build_step(cfg, shape_name: str, mesh, spec, force_micro=None,
 def _compile_once(cfg, shape_name, mesh, save_hlo_path=None,
                   force_micro=None,
                   policy=None) -> Dict[str, Any]:
-    ctx = (jax.sharding.use_mesh(mesh)
-           if hasattr(jax.sharding, "use_mesh") else mesh)
     t0 = time.time()
-    with ctx:
+    with jax.set_mesh(mesh):
         fn, args = build_step(cfg, shape_name, mesh, C.SHAPES[shape_name],
                               force_micro=force_micro,
                               policy=policy or DEFAULT_POLICY)

@@ -10,16 +10,17 @@ device state (the dry-run force-sets the host device count first).
 """
 from __future__ import annotations
 
-import jax
 from jax.sharding import Mesh
+
+from repro.runtime.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
     """Small mesh over host devices (tests / subprocess scaling runs)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

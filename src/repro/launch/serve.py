@@ -23,23 +23,29 @@ import numpy as np
 
 import repro.configs as C
 import repro.core as ft
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import init_params
 from repro.serving import ProtectedSession, ServingDriver
+
+
+def serving_inputs(cfg, batch: int, prompt_len: int, seed: int = 0):
+    """(params, prompts) of a serve() run, made from `seed`: one key
+    stream for the params, one for the prompts (a shared key would
+    correlate the weights with the traffic)."""
+    kp, kt = jax.random.split(jax.random.PRNGKey(seed))
+    params = init_params(kp, cfg)
+    tok_shape = ((batch, prompt_len, cfg.num_codebooks) if cfg.num_codebooks
+                 else (batch, prompt_len))
+    prompts = np.asarray(jax.random.randint(kt, tok_shape, 0,
+                                            cfg.vocab_size, jnp.int32))
+    return params, prompts
 
 
 def serve(arch: str, batch: int, prompt_len: int, gen: int, seed: int = 0,
           audit_every: int = 0, driver: bool = True):
     cfg = C.get(arch)
-    # split: one stream for params, one for prompts (a shared key would
-    # correlate the weights with the traffic)
-    kp, kt = jax.random.split(jax.random.PRNGKey(seed))
-    params = init_params(kp, cfg)
+    params, prompts = serving_inputs(cfg, batch, prompt_len, seed)
     max_len = prompt_len + gen
-
-    tok_shape = ((batch, prompt_len, cfg.num_codebooks) if cfg.num_codebooks
-                 else (batch, prompt_len))
-    prompts = np.asarray(jax.random.randint(kt, tok_shape, 0,
-                                            cfg.vocab_size, jnp.int32))
 
     plan = (ft.build_plan(params, cfg, batch=batch, seq=max_len)
             if cfg.abft else None)
@@ -91,6 +97,7 @@ def main():
     ap.add_argument("--sync", action="store_true",
                     help="use the synchronous ProtectedSession loop")
     args = ap.parse_args()
+    enable_compile_cache()
     toks, stats = serve(args.arch, args.batch, args.prompt_len, args.gen,
                         driver=not args.sync)
     rep = stats["report"]

@@ -18,7 +18,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import (FaultReport, ProtectConfig, ambient_mode,
-                        path_scope, protect_site, resolve_entry)
+                        path_scope, protect_site, protected_matmul,
+                        resolve_entry)
 from .linear import apply_dense, init_dense
 
 F32 = jnp.float32
@@ -62,8 +63,8 @@ def logits_head(params: Dict, x: jnp.ndarray, cfg,
                 y, rep = protect_site("table", (x, w), entry=entry,
                                       cfg=abft)
             else:
-                y = jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype))
-                rep = FaultReport.clean()
+                # the protected op's arithmetic (see apply_dense)
+                y, rep = protected_matmul(x, w, cfg=None)
         else:
             y, rep = apply_dense(params["head"], x, abft, name="head")
     y = y.astype(F32)

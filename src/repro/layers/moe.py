@@ -38,7 +38,7 @@ def _grouped(name: str, h, w, abft):
     if entry is not None or ambient_mode() is not None:
         return protect_site(name, (h, w), entry=entry, op=_GROUPED,
                             cfg=abft)
-    return protected_grouped_matmul(h, w, abft)
+    return protected_grouped_matmul(h, w, cfg=abft)
 
 F32 = jnp.float32
 

@@ -19,6 +19,7 @@ from repro.core import (DEFAULT_CONFIG, ModelReport, ProtectConfig,
                         ProtectedModel, ProtectionPlan, build_plan,
                         conv_entry, protect_site, resolve_entry)
 from repro.core.plan import ambient_plan
+from repro.core.protected import op_matmul
 
 F32 = jnp.float32
 
@@ -164,7 +165,8 @@ def _forward_pass(params: Dict, x: jnp.ndarray, cfg: CNNConfig,
                            (DEFAULT_CONFIG if cfg.abft else
                             DEFAULT_CONFIG.replace(enabled=False))),
                 stride=spec.stride, pad=spec.pad)
-        o = inject_o if i == inject_layer else None
+        o = _injected_output(i, x, params[name], spec, inject_layer,
+                             inject_o)
         y, r = protect_site(name,
                             (x, params[name]["w"], params[name]["b"]),
                             entry=entry, o=o)
@@ -195,13 +197,17 @@ def _forward_pass(params: Dict, x: jnp.ndarray, cfg: CNNConfig,
         names.append("fc")
         carries.append(r)
     else:
-        logits = x @ params["fc"]["w"] + params["fc"]["b"]
+        # the protected GEMM's own arithmetic, so a plan-less forward is
+        # bitwise the one a plan protects
+        w, b = params["fc"]["w"], params["fc"]["b"]
+        logits = op_matmul(x, w).astype(x.dtype) + b.astype(x.dtype)
     return logits, names, carries
 
 
 def forward_cnn(params: Dict, x: jnp.ndarray, cfg: CNNConfig,
                 policies: Optional[Sequence[ProtectConfig]] = None,
-                inject_layer: int = -1, inject_o=None, *,
+                inject_layer: int = -1,
+                inject_o: Optional[Dict[int, jnp.ndarray]] = None, *,
                 plan: Optional[ProtectionPlan] = None,
                 correction: str = "per_layer",
                 ) -> Tuple[jnp.ndarray, ModelReport]:
@@ -213,6 +219,10 @@ def forward_cnn(params: Dict, x: jnp.ndarray, cfg: CNNConfig,
     call under `policies[i]` (legacy shim) or the all-default config.
     inject_layer/inject_o: test hook - replaces layer i's conv output with
     a corrupted tensor before protection (the paper's per-layer injection).
+    `inject_o` is a {layer: corrupted output} dict and `inject_layer` (an
+    int or a traced scalar) picks the one entry injected; traced, one
+    compiled program serves every listed layer (and a clean run, for a
+    value that lists none).
 
     `correction` picks the workflow granularity:
     * "per_layer" (default) - every protected op carries its own in-graph
@@ -239,20 +249,37 @@ def forward_cnn(params: Dict, x: jnp.ndarray, cfg: CNNConfig,
     return ProtectedModel(apply_fn, plan)(params, x, correction=correction)
 
 
+def _conv_output(x: jnp.ndarray, p: Dict, spec: ConvSpec) -> jnp.ndarray:
+    """A conv layer's complete output (bias included), computed as the
+    protected op computes it."""
+    from repro.core.checksums import conv2d
+    o = conv2d(x, p["w"], stride=spec.stride,
+               padding=[(spec.pad, spec.pad)] * 2)
+    return (o.astype(F32) + p["b"][None, :, None, None]).astype(o.dtype)
+
+
+def _injected_output(i: int, x, p: Dict, spec: ConvSpec, inject_layer,
+                     inject_o):
+    """Layer i's output under the injection hook (None: not injected)."""
+    if not inject_o or i not in inject_o:
+        return None
+    return jnp.where(inject_layer == i, inject_o[i],
+                     _conv_output(x, p, spec))
+
+
 def conv_output_at(params: Dict, x: jnp.ndarray, cfg: CNNConfig,
                    layer: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(input_to_layer, clean_conv_output_of_layer) for injection tests."""
-    from repro.core.checksums import conv2d
+    """(input_to_layer, clean_conv_output_of_layer) for injection tests:
+    the forward's own layer walk, residual shortcuts included."""
+    feats = []
     for i, spec in enumerate(cfg.convs):
-        pad = [(spec.pad, spec.pad)] * 2
-        o = conv2d(x, params[f"conv{i}"]["w"], stride=spec.stride,
-                   padding=pad)
-        o = (o.astype(F32)
-             + params[f"conv{i}"]["b"][None, :, None, None]).astype(o.dtype)
+        o = _conv_output(x, params[f"conv{i}"], spec)
         if i == layer:
             return x, o
-        y = jax.nn.relu(o)
+        y = o if spec.residual_from < 0 else o + feats[spec.residual_from]
+        y = jax.nn.relu(y)
         if spec.pool:
             y = _maxpool(y, spec.pool)
+        feats.append(y)
         x = y
     raise ValueError(layer)

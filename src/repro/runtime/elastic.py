@@ -20,10 +20,6 @@ from jax.sharding import Mesh
 from .sharding import param_shardings
 
 
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    return jax.make_mesh(shape, axes)
-
-
 def replan_mesh(old_mesh: Mesh, lost_hosts: int, hosts_per_ring: int = 1
                 ) -> Tuple[int, ...]:
     """Shrink the data axis by the lost hosts, keeping the model axis (TP

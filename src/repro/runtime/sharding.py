@@ -13,10 +13,23 @@ Strategy on the (pod, data, model) production mesh:
 from __future__ import annotations
 
 import re
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices=None) -> Mesh:
+    """jax.make_mesh with Auto axis types. The rules below are GSPMD
+    rules (PartitionSpec constraints that the compiler propagates); under
+    JAX's default Explicit axes every array carries its sharding in its
+    type, and ops whose output sharding the rules leave to propagation (a
+    reshape of a data-sharded batch, a gather from a vocab-sharded table)
+    are rejected. Enter the mesh with `jax.set_mesh`."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def data_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -158,13 +171,13 @@ def _legalize(spec: P, shape, mesh: Mesh) -> P:
 
 
 def maybe_constrain(x, *spec):
-    """with_sharding_constraint that no-ops when no mesh is in scope (CPU
-    unit tests); inside the dry-run / drivers the mesh context is active
-    and the constraint pins GSPMD's propagation."""
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
+    """with_sharding_constraint that no-ops when no mesh is in scope
+    (single-device runs); inside the dry-run / drivers the mesh context
+    is active and the constraint pins GSPMD's propagation. Any error of
+    the constraint itself propagates."""
+    if jax.sharding.get_abstract_mesh().empty:
         return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def batch_spec(mesh: Mesh) -> P:

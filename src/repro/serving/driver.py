@@ -146,13 +146,14 @@ class ServingDriver(ProtectedSession):
         self._launches = 0              # decode launches (audit cadence)
         self._audits = 0
         self._audit_mark = 0
+        self._params_touched = False    # a pause may have swapped params
         self._busy_since: Optional[float] = None
         self._runner_t: Optional[threading.Thread] = None
         self._ctrl_t: Optional[threading.Thread] = None
 
         # decode inputs stay device-resident between steps; prefill
         # tokens are merged in with one tiny jitted update
-        self._d_tokens = jnp.asarray(self._h_tokens)
+        self._d_tokens = jnp.asarray(self._h_tokens.copy())
 
         def set_tok(big, small, slot):
             starts = ((jnp.asarray(slot, jnp.int32),)
@@ -310,6 +311,10 @@ class ServingDriver(ProtectedSession):
         finally:
             with self._mu:
                 self._pause -= 1
+                # whatever the pause did to params, the next launch must
+                # not serve it unaudited (an audit that finished just
+                # before the pause vouches for the old params only)
+                self._params_touched = True
                 self._work.notify_all()
 
     # -- shared predicates (call with _mu held) ----------------------------
@@ -322,8 +327,8 @@ class ServingDriver(ProtectedSession):
             return False
         if self._idle_locked():
             return False
-        if self._audits == 0:
-            return True            # trusted root: audit before first serve
+        if self._audits == 0 or self._params_touched:
+            return True            # trusted root: audit before serving
         return self._launches - self._audit_mark >= self.audit_every
 
     # -- the runner: launch / finalize / admit -----------------------------
@@ -473,6 +478,7 @@ class ServingDriver(ProtectedSession):
                         self._audit_req = False
                         self._audits += 1
                         self._audit_mark = self._launches
+                        self._params_touched = False
                         if err is not None:
                             self._error = err
                         self._done.notify_all()

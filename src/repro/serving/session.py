@@ -134,9 +134,7 @@ class ProtectedSession:
     def _ctx(self):
         if self.mesh is None:
             return contextlib.nullcontext()
-        if hasattr(jax.sharding, "use_mesh"):
-            return jax.sharding.use_mesh(self.mesh)
-        return self.mesh
+        return jax.set_mesh(self.mesh)
 
     # -- compiled pieces ---------------------------------------------------
     def _fix_cb(self, nxt):
@@ -367,7 +365,7 @@ class ProtectedSession:
 
         if self.scheduler.active:
             snap = self._snapshot_active()
-            out = self._dispatch_decode(jnp.asarray(self._h_tokens))
+            out = self._dispatch_decode(self._h_tokens.copy())
             for slot, _, _ in snap:
                 self._h_positions[slot] += 1
             self._apply_decode_outputs(np.asarray(out["next"]),
@@ -388,8 +386,12 @@ class ProtectedSession:
         """Launch one decode step over all slots (async; `tokens` may be
         host or device-resident). Chains the donated caches."""
         with self._ctx():
+            # a host copy: the host array advances right after this
+            # async launch, and the CPU backend may read a numpy argument
+            # in place (zero-copy) when the step runs - a jnp copy of it
+            # is itself an async read of the live array
             out = self._step_fn(self.params, tokens, self._caches,
-                                jnp.asarray(self._h_positions))
+                                self._h_positions.copy())
         self._caches = out["caches"]
         self.stats.counters["decode_steps"] += 1
         return out

@@ -45,7 +45,7 @@ def test_cnn_injection_corrected(layer):
     o_bad = inj.inject_conv(o_clean, p)
 
     logits, rep = cnn.forward_cnn(params, x, cfg, inject_layer=layer,
-                                  inject_o=o_bad)
+                                  inject_o={layer: o_bad})
     assert int(rep.detected) == 1
     assert int(rep.residual) == 0
     np.testing.assert_allclose(np.asarray(logits), np.asarray(clean_logits),

@@ -50,8 +50,24 @@ def test_measure_peaks_stale_backend_cache_rejected(tmp_path):
         "schema": CACHE_SCHEMA, "backend": "not-a-backend",
         "host": "x", "peak_flops": 1.0, "hbm_bw": 1.0}))
     p = measure_peaks(cache_path=str(path))
-    assert p.source in ("measured", "fallback")
+    assert p.source == "measured"
     assert p.peak_flops != 1.0
+
+
+def test_measure_peaks_raises_when_a_bench_cannot_run(tmp_path,
+                                                     monkeypatch):
+    """No made-up peaks: a microbench that fails fails the calibration
+    (and writes no cache) instead of degrading to fallback numbers."""
+    from repro.core import cost_model as CM
+
+    def broken():
+        raise RuntimeError("no device")
+
+    monkeypatch.setattr(CM, "_bench_gemm_flops", broken)
+    path = tmp_path / "roofline.json"
+    with pytest.raises(RuntimeError, match="no device"):
+        measure_peaks(cache_path=str(path), refresh=True)
+    assert not path.exists()
 
 
 def test_measure_peaks_refresh_overwrites(tmp_path):

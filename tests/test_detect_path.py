@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jaxpr_walk import eqns as walk_eqns
 
 import repro.core as core
 from repro.core import checksums as C
@@ -37,20 +38,16 @@ F32 = jnp.float32
 def _outer_eqns(jaxpr):
     """Equations of `jaxpr` and of every inner jaxpr EXCEPT cond branches
     (the correction ladder); pjit/closed_call bodies are inlined."""
-    eqns = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "cond":
-            continue
-        eqns.append(eqn)
-        for v in eqn.params.values():
-            for sub in jax.tree_util.tree_leaves(
-                    v, is_leaf=lambda x: isinstance(
-                        x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))):
-                if isinstance(sub, jax.core.ClosedJaxpr):
-                    eqns.extend(_outer_eqns(sub.jaxpr))
-                elif isinstance(sub, jax.core.Jaxpr):
-                    eqns.extend(_outer_eqns(sub))
-    return eqns
+    return walk_eqns(jaxpr, skip=("cond",))
+
+
+def _convs(eqns):
+    """The convolutions among `eqns`: conv primitives, and the checksum
+    convs, which run as one contraction each (checksums.checksum_conv)."""
+    return [e for e in eqns
+            if e.primitive.name == "conv_general_dilated"
+            or (e.primitive.name == "dot_general"
+                and "checksum_conv" in str(e.source_info.name_stack))]
 
 
 def _size(var) -> int:
@@ -101,7 +98,7 @@ def test_conv_errorfree_path_structure(detect_only):
     jaxpr = jax.make_jaxpr(
         lambda d, w, b: protected_conv(d, w, bias=b, cfg=cfg)[0])(d, w, b)
     eqns = _outer_eqns(jaxpr.jaxpr)
-    convs = [e for e in eqns if e.primitive.name == "conv_general_dilated"]
+    convs = _convs(eqns)
     # exactly the protected op itself + ONE fused checksum conv; the old
     # path's separate c5/c6/c7/absdot convs (and the correction branch's
     # c1-c4 convs) would push this to 5+
@@ -122,7 +119,7 @@ def test_matmul_errorfree_path_structure(detect_only):
     jaxpr = jax.make_jaxpr(
         lambda d, w: protected_matmul(d, w, cfg=cfg)[0])(d, w)
     eqns = _outer_eqns(jaxpr.jaxpr)
-    assert not any(e.primitive.name == "conv_general_dilated" for e in eqns)
+    assert not _convs(eqns)
     dots = [e for e in eqns if e.primitive.name == "dot_general"]
     main_flops = N * K_MM * M_MM
     heavy = [e for e in dots if _dot_flops(e) >= main_flops / 2]
@@ -140,22 +137,7 @@ def _outer_eqns_no_pallas(jaxpr):
     detect kernel's inner jaxpr legitimately holds the GEMM dot and the
     epilogue reductions, so recursing into it would count the very ops
     whose absence OUTSIDE the kernel these assertions pin."""
-    eqns = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "cond":
-            continue
-        eqns.append(eqn)
-        if eqn.primitive.name == "pallas_call":
-            continue
-        for v in eqn.params.values():
-            for sub in jax.tree_util.tree_leaves(
-                    v, is_leaf=lambda x: isinstance(
-                        x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))):
-                if isinstance(sub, jax.core.ClosedJaxpr):
-                    eqns.extend(_outer_eqns_no_pallas(sub.jaxpr))
-                elif isinstance(sub, jax.core.Jaxpr):
-                    eqns.extend(_outer_eqns_no_pallas(sub))
-    return eqns
+    return walk_eqns(jaxpr, skip=("cond",), opaque=("pallas_call",))
 
 
 def test_fused_detect_only_is_single_launch():
@@ -223,25 +205,10 @@ def test_conv_correction_stays_in_cond():
     d, w, b = _conv_operands()
     cfg = T.DEFAULT_CONFIG
 
-    def count_convs(jaxpr):
-        n = len([e for e in jaxpr.eqns
-                 if e.primitive.name == "conv_general_dilated"])
-        for eqn in jaxpr.eqns:
-            for v in eqn.params.values():
-                for sub in jax.tree_util.tree_leaves(
-                        v, is_leaf=lambda x: isinstance(
-                            x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))):
-                    if isinstance(sub, jax.core.ClosedJaxpr):
-                        n += count_convs(sub.jaxpr)
-                    elif isinstance(sub, jax.core.Jaxpr):
-                        n += count_convs(sub)
-        return n
-
     jaxpr = jax.make_jaxpr(
         lambda d, w, b: protected_conv(d, w, bias=b, cfg=cfg)[0])(d, w, b)
-    total = count_convs(jaxpr.jaxpr)
-    outer = len([e for e in _outer_eqns(jaxpr.jaxpr)
-                 if e.primitive.name == "conv_general_dilated"])
+    total = len(_convs(walk_eqns(jaxpr.jaxpr)))
+    outer = len(_convs(_outer_eqns(jaxpr.jaxpr)))
     assert outer == 2
     assert total > outer  # ladder rungs really are traced, behind the cond
 
@@ -411,23 +378,9 @@ def test_detect_only_mode_traces_no_correction_machinery():
     jaxpr = jax.make_jaxpr(
         lambda d, w, b: core.protect_op(core.OpSpec("conv"), (d, w, b),
                                         mode="detect_only")[0])(d, w, b)
-
-    def all_eqns(jx):
-        out = list(jx.eqns)
-        for eqn in jx.eqns:
-            for v in eqn.params.values():
-                for sub in jax.tree_util.tree_leaves(
-                        v, is_leaf=lambda x: isinstance(
-                            x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))):
-                    if isinstance(sub, jax.core.ClosedJaxpr):
-                        out += all_eqns(sub.jaxpr)
-                    elif isinstance(sub, jax.core.Jaxpr):
-                        out += all_eqns(sub)
-        return out
-
-    eqns = all_eqns(jaxpr.jaxpr)
+    eqns = walk_eqns(jaxpr.jaxpr)
     assert not any(e.primitive.name == "cond" for e in eqns)
-    convs = [e for e in eqns if e.primitive.name == "conv_general_dilated"]
+    convs = _convs(eqns)
     assert len(convs) == 2    # the op + ONE fused checksum conv, nothing else
 
 
@@ -500,9 +453,9 @@ def test_deferred_injection_parity(cnn_model, fault):
                       o_clean.shape[2] * o_clean.shape[3], 64)
     o_bad = inj.inject(o_clean, spec, model)
     l_pl, r_pl = cnn.forward_cnn(params, x, cfg, plan=plan,
-                                 inject_layer=layer, inject_o=o_bad)
+                                 inject_layer=layer, inject_o={layer: o_bad})
     l_df, r_df = cnn.forward_cnn(params, x, cfg, plan=plan,
-                                 inject_layer=layer, inject_o=o_bad,
+                                 inject_layer=layer, inject_o={layer: o_bad},
                                  correction="deferred")
     scale = float(np.max(np.abs(np.asarray(l_pl)))) + 1.0
     np.testing.assert_allclose(np.asarray(l_pl), np.asarray(l_df),
@@ -616,7 +569,7 @@ def test_mixed_injection_corrects_in_both_memberships(
                       o_clean.shape[2] * o_clean.shape[3], 64)
     o_bad = inj.inject(o_clean, spec, model)
     l_mix, rep = cnn.forward_cnn(params, x, cfg, plan=plan,
-                                 inject_layer=layer, inject_o=o_bad,
+                                 inject_layer=layer, inject_o={layer: o_bad},
                                  correction="deferred")
     assert int(rep.by_layer[f"conv{layer}"].detected) == 1
     assert int(rep.by_layer[f"conv{layer}"].corrected_by) > 0

@@ -39,8 +39,9 @@ _SCRIPT = textwrap.dedent("""
     ref_state, ref_m = step0(state0, batch)
 
     # sharded: (data=4, model=2)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
-    with mesh:
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(4, 2)
+    with jax.set_mesh(mesh):
         state = init_train_state(jax.random.PRNGKey(0), cfg, opt)
         psh = SH.param_shardings(state["params"], mesh, cfg)
         osh = SH.param_shardings(state["opt"], mesh, cfg)

@@ -12,6 +12,9 @@ SHAPES = [(64, 32, 48), (128, 128, 128), (256, 64, 512), (96, 160, 224),
 DTYPES = [jnp.float32, jnp.bfloat16]
 
 
+PARTS = ["colsum", "wcolsum", "sqsum"]
+
+
 @pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_abft_matmul_vs_ref(shape, dtype):
@@ -21,16 +24,18 @@ def test_abft_matmul_vs_ref(shape, dtype):
     w = jax.random.normal(jax.random.fold_in(key, 1), (k, m),
                           jnp.float32).astype(dtype)
     o, parts = ops.abft_matmul(d, w, interpret=True)
-    o_ref, parts_ref = ref.abft_matmul_ref(d, w, parts[3], parts[4])
+    o_ref, sums_ref = ref.abft_matmul_ref(d, w, parts.bm)
     # kernel accumulates over bk-sized K steps; the oracle in one dot -
     # fp32 reassociation noise only
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(o_ref, np.float32),
                                rtol=1e-5, atol=1e-4 * k ** 0.5)
-    for a, b, name in zip(parts[:3], parts_ref[:3],
-                          ["colsum", "rowsum", "sumsq"]):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
-                                   atol=1e-3 * k ** 0.5, err_msg=name)
+    for r, name in enumerate(PARTS):
+        np.testing.assert_allclose(np.asarray(parts.sums[:, r]),
+                                   np.asarray(sums_ref[:, r]), rtol=1e-5,
+                                   atol=1e-3 * k ** 0.5 * (
+                                       parts.bm if name == "wcolsum" else 1),
+                                   err_msg=name)
 
 
 @pytest.mark.parametrize("shape", [(64, 48), (512, 384), (128, 1024)])
@@ -38,46 +43,45 @@ def test_abft_matmul_vs_ref(shape, dtype):
 def test_checksum_reduce_vs_ref(shape, dtype):
     key = jax.random.PRNGKey(shape[0])
     o = jax.random.normal(key, shape, jnp.float32).astype(dtype)
-    colsum, rowsum, sumsq, wcolsum, bm, bn = ops.checksum_reduce(
-        o, interpret=True)
-    cr, rr, sr, wr = ref.checksum_reduce_ref(o, bm, bn)
-    np.testing.assert_allclose(np.asarray(colsum), np.asarray(cr), rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(rowsum), np.asarray(rr), rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(sumsq), np.asarray(sr), rtol=1e-5)
+    parts = ops.checksum_reduce(o, interpret=True)
+    want = ref.checksum_reduce_ref(o, parts.bm)
+    np.testing.assert_allclose(np.asarray(parts.sums[:, 0]),
+                               np.asarray(want[:, 0]), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(parts.sums[:, 2]),
+                               np.asarray(want[:, 2]), rtol=1e-5)
     # weights up to bm-1 amplify magnitudes (and reassociation noise)
-    wscale = float(np.max(np.abs(np.asarray(wr)))) + 1.0
-    np.testing.assert_allclose(np.asarray(wcolsum), np.asarray(wr),
-                               atol=1e-6 * wscale)
+    wscale = float(np.max(np.abs(np.asarray(want[:, 1])))) + 1.0
+    np.testing.assert_allclose(np.asarray(parts.sums[:, 1]),
+                               np.asarray(want[:, 1]), atol=1e-6 * wscale)
 
 
-@pytest.mark.parametrize("shape", [(37, 53), (100, 260), (96, 100)])
+@pytest.mark.parametrize("shape", [(1100, 300), (40, 1100), (1100, 1100)])
 def test_checksum_reduce_padded_edges(shape):
-    """Non-tile-aligned shapes run the kernel on zero-padded operands and
-    slice back - partials must match the element-resolution oracle."""
+    """Axes past the one-block cap that no aligned tile divides run the
+    kernel on zero-padded operands and slice back - partial totals must
+    match the element-resolution values."""
     key = jax.random.PRNGKey(sum(shape))
     o = jax.random.normal(key, shape, jnp.float32)
-    colsum, rowsum, sumsq, wcolsum, bm, bn = ops.checksum_reduce(
-        o, interpret=True)
+    parts = ops.checksum_reduce(o, interpret=True)
     n, m = shape
-    assert colsum.shape == (-(-n // bm), m)
-    assert rowsum.shape[0] == n
+    assert parts.sums.shape == (-(-n // parts.bm), 3, m)
+    assert parts.n == n
+    assert n % parts.bm or m % parts.bn        # padding really happened
     # totals are exact regardless of tiling
-    np.testing.assert_allclose(float(jnp.sum(colsum)), float(jnp.sum(o)),
-                               rtol=1e-5)
-    np.testing.assert_allclose(float(jnp.sum(rowsum)), float(jnp.sum(o)),
-                               rtol=1e-5)
-    np.testing.assert_allclose(float(jnp.sum(sumsq)), float(jnp.sum(o * o)),
-                               rtol=1e-5)
+    np.testing.assert_allclose(float(jnp.sum(parts.sums[:, 0])),
+                               float(jnp.sum(o)), rtol=1e-5)
+    np.testing.assert_allclose(float(jnp.sum(parts.sums[:, 2])),
+                               float(jnp.sum(o * o)), rtol=1e-5)
 
 
-@pytest.mark.parametrize("rb,cb", [(64, 64), (128, 256), (256, 128)])
+@pytest.mark.parametrize("rb,cb", [(64, 128), (128, 256), (256, 128)])
 def test_chunk_sums_from_partials(rb, cb):
     key = jax.random.PRNGKey(0)
     n, k, m = 256, 64, 512
     d = jax.random.normal(key, (n, k))
     w = jax.random.normal(jax.random.fold_in(key, 1), (k, m))
     o, parts = ops.abft_matmul(d, w, interpret=True, bm=min(64, rb),
-                               bn=min(64, cb))
+                               bn=min(128, cb))
     s = ops.chunk_sums_from_partials(parts, rb, cb)
     sref = ref.chunk_sums_ref(jnp.asarray(o, jnp.float32), rb, cb)
     for a, b, name in zip(s, sref, ["s5", "s6", "s7", "sumsq"]):
@@ -101,32 +105,32 @@ def test_fused_protection_end_to_end():
 
 
 def test_unaligned_fallback():
-    """Odd shapes run via padded edge tiles (or the oracle when
-    degenerate) without changing semantics."""
+    """Odd shapes run the kernel as whole-axis blocks without changing
+    semantics."""
     key = jax.random.PRNGKey(9)
     d = jax.random.normal(key, (37, 19))
     w = jax.random.normal(jax.random.fold_in(key, 1), (19, 53))
     o, parts = ops.abft_matmul(d, w, interpret=True)
+    assert (parts.bm, parts.bn) == (37, 53)
     np.testing.assert_allclose(np.asarray(o), np.asarray(d @ w), rtol=1e-5,
                                atol=1e-5)
 
 
-@pytest.mark.parametrize("shape", [(40, 24, 56), (100, 96, 136)])
+@pytest.mark.parametrize("shape", [(1100, 24, 56), (40, 1100, 136)])
 def test_abft_matmul_padded_edges(shape):
-    """Shapes whose axes don't divide the default tiles still run the
-    fused kernel via zero padding; O and the partial totals stay exact."""
+    """Axes that no legal tile divides still run the fused kernel via zero
+    padding; O and the partial totals stay exact."""
     n, k, m = shape
     key = jax.random.PRNGKey(n + m)
     d = jax.random.normal(key, (n, k))
     w = jax.random.normal(jax.random.fold_in(key, 2), (k, m))
-    o, parts = ops.abft_matmul(d, w, interpret=True)
+    o, parts = ops.abft_matmul(d, w, interpret=True, bm=32, bn=128, bk=128)
     np.testing.assert_allclose(np.asarray(o), np.asarray(d @ w), rtol=1e-5,
                                atol=1e-4)
-    colsum, rowsum, sumsq = parts[0], parts[1], parts[2]
-    assert colsum.shape[1] == m and rowsum.shape[0] == n
-    np.testing.assert_allclose(float(jnp.sum(colsum)), float(jnp.sum(o)),
-                               rtol=1e-5)
-    np.testing.assert_allclose(float(jnp.sum(sumsq)),
+    assert parts.sums.shape == (-(-n // parts.bm), 3, m) and parts.n == n
+    np.testing.assert_allclose(float(jnp.sum(parts.sums[:, 0])),
+                               float(jnp.sum(o)), rtol=1e-5)
+    np.testing.assert_allclose(float(jnp.sum(parts.sums[:, 2])),
                                float(jnp.sum(jnp.square(d @ w))), rtol=1e-4)
 
 
@@ -157,7 +161,6 @@ def test_conv_detect_sums_vs_jnp(oshape):
     key = jax.random.PRNGKey(oshape[1])
     o = jax.random.normal(key, oshape, jnp.float32)
     got = ops.conv_detect_sums(o, interpret=True, tiles=(8, 64))
-    assert got is not None
     want = C.detect_sums(o)
     for a, b, name in zip(got, want, ["s5", "s6", "s7", "sumsq"]):
         scale = float(jnp.max(jnp.abs(jnp.atleast_1d(b)))) + 1.0
@@ -188,8 +191,8 @@ def test_abft_matmul_detect_clean_and_tampered(dtype):
     flag clear and output matches the dot; a corrupted checksum -> the
     owning tile (and only it) flags with score > 1."""
     from repro.core import thresholds as TH
-    n, k, m = 32, 64, 96
-    rb, cb = 16, 48
+    n, k, m = 32, 64, 256
+    rb, cb = 16, 128
     key = jax.random.PRNGKey(5)
     d = jax.random.normal(key, (n, k), jnp.float32).astype(dtype)
     w = jax.random.normal(jax.random.fold_in(key, 1), (k, m),
@@ -217,32 +220,70 @@ def test_abft_matmul_detect_refuses_misaligned_chunks():
     """Chunkings the kernel cannot launch as tiles signal the partials
     route with None instead of computing something wrong."""
     d = jnp.ones((32, 64))
-    w = jnp.ones((64, 96))
+    w = jnp.ones((64, 256))
     z = jnp.zeros((8, 2))
-    # rb=4 is below the minimum tile
-    assert ops.abft_matmul_detect(d, w, z, z, z, z, rb=4, cb=48,
-                                  tau_a=1.0, tau_b=1.0) is None
+    # rb=4 is below the minimum (sublane) tile
+    assert ops.abft_matmul_detect(d, w, z, z, z, z, rb=4, cb=128,
+                                  tau_a=1.0, tau_b=1.0,
+                                  interpret=True) is None
+    # cb=64 is not a whole number of lane tiles
+    z2 = jnp.zeros((2, 4))
+    assert ops.abft_matmul_detect(d, w, z2, z2, z2, z2, rb=16, cb=64,
+                                  tau_a=1.0, tau_b=1.0,
+                                  interpret=True) is None
     # checksum grid does not match the (rb, cb) chunking
-    assert ops.abft_matmul_detect(d, w, z, z, z, z, rb=16, cb=48,
-                                  tau_a=1.0, tau_b=1.0) is None
+    assert ops.abft_matmul_detect(d, w, z, z, z, z, rb=16, cb=128,
+                                  tau_a=1.0, tau_b=1.0,
+                                  interpret=True) is None
 
 
-def test_kernels_survive_absent_pltpu(monkeypatch):
-    """Interpret mode is the documented fallback for jaxlib builds where
-    the pallas.tpu import fails - so it must not dereference the absent
-    module (the VMEM scratch spec used to)."""
-    from repro.kernels import abft_matmul as K
-    monkeypatch.setattr(K, "pltpu", None)
-    key = jax.random.PRNGKey(9)
-    d = jax.random.normal(key, (16, 32))
-    w = jax.random.normal(jax.random.fold_in(key, 1), (32, 16))
-    o, _ = ops.abft_matmul(d, w, interpret=True, bm=8, bn=8, bk=8)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(jnp.dot(d, w)),
-                               rtol=1e-5, atol=1e-4)
-    c5, c6, c7, absdot = _chunk_checksums_ref(d, w, 8, 8)
-    o2, flag, _ = ops.abft_matmul_detect(
-        d, w, c5, c6, c7, absdot, rb=8, cb=8, tau_a=1e-5, tau_b=1e-7,
-        interpret=True)
-    assert int(flag.sum()) == 0
-    np.testing.assert_allclose(np.asarray(o2), np.asarray(jnp.dot(d, w)),
-                               rtol=1e-5, atol=1e-4)
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_abft_matmul_detect_vs_ref(dtype):
+    """Kernel verdicts equal the ref.py oracle's, tile by tile, for clean
+    and for tampered checksum predictions."""
+    from repro.core import thresholds as TH
+    n, k, m = 64, 96, 256
+    rb, cb = 32, 128
+    key = jax.random.PRNGKey(21)
+    d = jax.random.normal(key, (n, k), jnp.float32).astype(dtype)
+    w = jax.random.normal(jax.random.fold_in(key, 1), (k, m),
+                          jnp.float32).astype(dtype)
+    cs = ref.chunk_checksums_ref(d, w, rb, cb)
+    tau_a, tau_b = TH.tau_scalar_coeffs(k, dtype, 32.0)
+    for c5 in (cs[0], cs[0].at[1, 1].add(1e3)):
+        got = ops.abft_matmul_detect(d, w, c5, *cs[1:], rb=rb, cb=cb,
+                                     tau_a=tau_a, tau_b=tau_b,
+                                     interpret=True)
+        want = ref.abft_matmul_detect_ref(d, w, c5, *cs[1:], rb, cb,
+                                          tau_a, tau_b)
+        np.testing.assert_array_equal(np.asarray(got[1]),
+                                      np.asarray(want[1]))
+        # scores are |c - s| / tau: clean tiles carry rounding noise far
+        # below 1 on both sides, so compare them in units of tau
+        np.testing.assert_allclose(np.asarray(got[2]), np.asarray(want[2]),
+                                   rtol=1e-4, atol=1e-2)
+        np.testing.assert_allclose(np.asarray(got[0], np.float32),
+                                   np.asarray(want[0], np.float32),
+                                   rtol=1e-5, atol=1e-4)
+
+
+def test_conv_detect_sums_vs_ref():
+    o = jax.random.normal(jax.random.PRNGKey(4), (4, 40, 7, 7))
+    got = ops.conv_detect_sums(o, interpret=True)
+    for a, b, name in zip(got, ref.conv_detect_sums_ref(o),
+                          ["s5", "s6", "s7", "sumsq"]):
+        scale = float(jnp.max(jnp.abs(jnp.atleast_1d(b)))) + 1.0
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-5 * scale, err_msg=name)
+
+
+@pytest.mark.parametrize("entry", ["abft_matmul", "checksum_reduce",
+                                   "conv_detect_sums"])
+def test_kernel_entry_points_require_interpret(entry):
+    """No kernel entry point defaults to interpret mode: a caller that
+    forgets the flag fails instead of interpreting on the chip."""
+    x = jnp.ones((8, 8, 4, 4)) if entry == "conv_detect_sums" \
+        else jnp.ones((8, 128))
+    args = (x, jnp.ones((128, 128))) if entry == "abft_matmul" else (x,)
+    with pytest.raises(TypeError, match="interpret"):
+        getattr(ops, entry)(*args)

@@ -277,7 +277,8 @@ def test_plan_forward_injection_attributed_to_layer():
     o_bad = inj.inject_conv(o_clean, p)
     clean_logits, _ = cnn.forward_cnn(params, x, cfg, plan=plan)
     logits, rep = cnn.forward_cnn(params, x, cfg, plan=plan,
-                                  inject_layer=layer, inject_o=o_bad)
+                                  inject_layer=layer,
+                                  inject_o={layer: o_bad})
     assert int(rep.by_layer[f"conv{layer}"].detected) == 1
     assert int(rep.by_layer[f"conv{layer}"].residual) == 0
     for name in rep.by_layer:
@@ -443,3 +444,20 @@ def test_matmul_profile_fairness_same_outputs():
         scale = float(jnp.max(jnp.abs(a))) + 1.0
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-4 * scale, err_msg=name)
+
+
+def test_plan_records_operand_precision_and_refuses_another():
+    """A plan's weight checksums encode the operand values of the backend
+    it was built on (types.op_operand_dtype); entering it where f32 ops
+    multiply other operands is a PlanStaleError, not clean-traffic
+    false positives."""
+    plan = core.build_plan(None, cnn.alexnet(0.12), batch=2)
+    assert plan.meta["f32_operands"] == str(
+        core.types.op_operand_dtype(jnp.float32))
+    with core.plan_scope(plan):
+        pass
+    other = core.ProtectionPlan(entries=dict(plan.entries),
+                                meta={**plan.meta, "f32_operands": "int8"})
+    with pytest.raises(core.PlanStaleError, match="operands"):
+        with core.plan_scope(other):
+            pass

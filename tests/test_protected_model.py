@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jaxpr_walk import eqns as walk_eqns
 
 import repro.core as core
 from repro.configs.base import ModelConfig
@@ -368,25 +369,11 @@ def test_fused_pinned_scan_body_one_launch_per_gemm():
         jaxpr = jax.make_jaxpr(
             lambda p, t: M.train_apply(cfg)(p, t)[0][0])(params, tokens)
 
-    def eqns_no_pallas(jx):
-        out = []
-        for eqn in jx.eqns:
-            out.append(eqn)
-            if eqn.primitive.name == "pallas_call":
-                continue
-            for v in eqn.params.values():
-                for sub in jax.tree_util.tree_leaves(
-                        v, is_leaf=lambda x: isinstance(
-                            x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))):
-                    if isinstance(sub, jax.core.ClosedJaxpr):
-                        out.extend(eqns_no_pallas(sub.jaxpr))
-                    elif isinstance(sub, jax.core.Jaxpr):
-                        out.extend(eqns_no_pallas(sub))
-        return out
 
     scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
     assert len(scans) == 1
-    body = eqns_no_pallas(scans[0].params["jaxpr"].jaxpr)
+    body = walk_eqns(scans[0].params["jaxpr"].jaxpr,
+                     opaque=("pallas_call",))
     launches = [e for e in body if e.primitive.name == "pallas_call"]
     assert len(launches) == 7, len(launches)
     # rows=16, smallest protected GEMM K=64, M=32

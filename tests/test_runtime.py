@@ -127,3 +127,45 @@ def test_policy_calibration_recovers_coefficients():
     fit = calibrate(samples)
     assert abs(fit.alpha - true.alpha) / true.alpha < 0.05
     assert abs(fit.beta - true.beta) / true.beta < 0.05
+
+
+def test_constraint_helpers_noop_only_without_a_mesh():
+    """The sharding-constraint helpers return their input untouched when
+    no mesh is in scope, constrain under `jax.set_mesh`, and let any
+    other error of the constraint propagate."""
+    from repro.core.protected import _replicate_small
+    from repro.runtime.sharding import make_mesh, maybe_constrain
+    x = jnp.arange(8.0).reshape(2, 4)
+    assert jax.sharding.get_abstract_mesh().empty
+    assert maybe_constrain(x, "data", None) is x
+    assert _replicate_small(x) is x
+    mesh = make_mesh((1,), ("data",))
+    with jax.set_mesh(mesh):
+        assert not jax.sharding.get_abstract_mesh().empty
+        y = jax.jit(lambda a: maybe_constrain(a, "data", None))(x)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
+        z = jax.jit(_replicate_small)(x)
+        np.testing.assert_array_equal(np.asarray(z), np.asarray(x))
+        with pytest.raises(Exception):
+            jax.jit(lambda a: maybe_constrain(a, "no_such_axis"))(x)
+
+
+def test_compile_cache_dir_follows_env_else_repo(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX (no other
+    directory configured); otherwise the fixed <repo>/.jax_cache."""
+    import os
+
+    from repro.launch import compile_cache as CC
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(CC.ENV, "/somewhere/else")
+        assert CC.enable_compile_cache() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv(CC.ENV)
+        got = CC.enable_compile_cache()
+        repo = os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                            ".."))
+        assert got == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

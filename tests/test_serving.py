@@ -400,7 +400,8 @@ _MESH_SCRIPT = textwrap.dedent("""
     max_len, gen = 24, 4
     params = M.init_params(jax.random.PRNGKey(0), cfg)
     plan = ft.build_plan(params, cfg, batch=4, seq=max_len)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(2, 2)
 
     sess = ProtectedSession(params, cfg, plan, slots=4, max_len=max_len,
                             mesh=mesh, audit_every=4)
