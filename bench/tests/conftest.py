@@ -1,0 +1,16 @@
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+for p in (ROOT, os.path.join(ROOT, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+
+@pytest.fixture
+def bench_doc():
+    from bench.tests.helpers import ROOT, load
+    return load(ROOT, "BENCHMARK.json")
