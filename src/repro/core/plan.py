@@ -461,7 +461,11 @@ def protect_site(name: str, inputs, *, entry: Optional[PlanEntry] = None,
     stage carry) re-derive their own flag.
 
     Everything the site traces sits under a named scope of its path, so
-    compiled HLO and profiler traces attribute each kernel to its site.
+    compiled HLO and profiler traces attribute each kernel to its site,
+    and under that to one phase scope: `op` (the op and its bias add),
+    `encode` (the input checksums), `detect` (CoC-D), `correct` (the
+    ladder; it also wraps the deferred workflow's whole rerun) or
+    `inject` (a fault hook's planted output).
     """
     with jax.named_scope(current_path(name)):
         return _protect_site(name, inputs, entry, cfg, o, op)
@@ -505,11 +509,13 @@ def _protect_site(name, inputs, entry, cfg, o, op):
             d2 = d.reshape(-1, k)
             # same spelling as protected_matmul's raw product, so rows the
             # hook leaves alone stay bitwise identical to the clean path
-            o2 = op_output(op_matmul(d2, w), d.dtype)
-            if len(inputs) > 2:
-                o2 = op_output(o2.astype(jnp.float32)
-                               + inputs[2].astype(jnp.float32), o2.dtype)
-            o2 = hook(o2.reshape(*lead, m))
+            with jax.named_scope("op"):
+                o2 = op_output(op_matmul(d2, w), d.dtype)
+                if len(inputs) > 2:
+                    o2 = op_output(o2.astype(jnp.float32)
+                                   + inputs[2].astype(jnp.float32), o2.dtype)
+            with jax.named_scope("inject"):
+                o2 = hook(o2.reshape(*lead, m))
             out, rep = protect_op(op, (d2,) + tuple(inputs[1:]),
                                   entry=entry, cfg=use_cfg,
                                   o=o2.reshape(-1, m), mode=mode,

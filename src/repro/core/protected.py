@@ -70,6 +70,13 @@ def op_matmul(d2: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
                    preferred_element_type=F32)
 
 
+def _add_bias(o: jnp.ndarray, bias: Optional[jnp.ndarray]) -> jnp.ndarray:
+    """O + bias over the last axis, added in f32 and rounded back."""
+    if bias is None:
+        return o
+    return op_output(o.astype(F32) + bias.astype(F32), o.dtype)
+
+
 def pick_chunk(n: int, target: int) -> int:
     """Largest divisor of n that is <= target (n itself if n <= target)."""
     if n <= target:
@@ -327,21 +334,21 @@ def protect_matmul_output(
     cb = wck.col_chunk if wck is not None else pick_chunk(m, cfg.col_chunk)
     nb, mb = n // rb, m // cb
 
-    if wck is None:
-        wck = weight_checksums_matmul(w, cb)
     if recompute_fn is None:
         def recompute_fn():
-            fresh = op_matmul(d2, w)
-            if bias is not None:
-                fresh = fresh + bias.astype(F32)
-            return fresh.astype(o.dtype)
+            with jax.named_scope("op"):
+                fresh = op_matmul(d2, w)
+                if bias is not None:
+                    fresh = fresh + bias.astype(F32)
+                return fresh.astype(o.dtype)
 
-    cd1, cd2 = _encode_d_chunked(d2, rb)
-    cs = _scalar_checksums(cd1, cd2, wck)
-    if tamper_checksums is not None:
-        cs = tamper_checksums(cs)
-
-    adj = _bias_adjust(bias, cb) if bias is not None else None
+    with jax.named_scope("encode"):
+        if wck is None:
+            wck = weight_checksums_matmul(w, cb)
+        cd1, cd2 = _encode_d_chunked(d2, rb)
+        cs = _scalar_checksums(cd1, cd2, wck)
+        if tamper_checksums is not None:
+            cs = tamper_checksums(cs)
 
     def _adjusted_scalars(cs):
         """c5/c6/c7 with the bias contribution added (Table 5)."""
@@ -353,37 +360,42 @@ def protect_matmul_output(
             c7 = c7 + rb * adj.b_chunk_wsum[None, :]
         return c5, c6, c7
 
-    if mode == "correct" and detected is not None:
-        # the caller carries the CoC-D verdict (a DetectEvidence flag from
-        # the detect-only pass): trust it and skip the O(|O|) detection
-        # sums + compare entirely - the ladder re-derives everything it
-        # verifies against, so nothing is lost, and the deferred
-        # correction branch stays one detection pass per op smaller
-        detected = jnp.asarray(detected).astype(jnp.bool_).reshape(())
-    else:
-        if precomputed_sums is not None:
-            # kernel partials are RAW-product sums (reduced before the
-            # bias add), so compare them against the unadjusted
-            # checksums: adding the analytic bias term to one side only
-            # would false-flag every bias-carrying fused site, and
-            # adding it to both sides cancels exactly
-            s5, s6, s7, sumsq = precomputed_sums
-            c5a, c6a, c7a = cs.c5, cs.c6, cs.c7
+    with jax.named_scope("detect"):
+        adj = _bias_adjust(bias, cb) if bias is not None else None
+        if mode == "correct" and detected is not None:
+            # the caller carries the CoC-D verdict (a DetectEvidence flag
+            # from the detect-only pass): trust it and skip the O(|O|)
+            # detection sums + compare entirely - the ladder re-derives
+            # everything it verifies against, so nothing is lost, and the
+            # deferred correction branch stays one detection pass per op
+            # smaller
+            detected = jnp.asarray(detected).astype(jnp.bool_).reshape(())
         else:
-            s5, s6, s7, sumsq = _chunk_sums(o, rb, cb)
-            c5a, c6a, c7a = _adjusted_scalars(cs)
+            if precomputed_sums is not None:
+                # kernel partials are RAW-product sums (reduced before the
+                # bias add), so compare them against the unadjusted
+                # checksums: adding the analytic bias term to one side
+                # only would false-flag every bias-carrying fused site,
+                # and adding it to both sides cancels exactly
+                s5, s6, s7, sumsq = precomputed_sums
+                c5a, c6a, c7a = cs.c5, cs.c6, cs.c7
+            else:
+                s5, s6, s7, sumsq = _chunk_sums(o, rb, cb)
+                c5a, c6a, c7a = _adjusted_scalars(cs)
 
-        tau5 = TH.tau_scalar(sumsq, k, o.dtype, cfg.tau_factor, cs.absdot)
-        flag, score = _detect_invariants(c5a, c6a, c7a, s5, s6, s7, tau5,
-                                         rb, cb, cfg.detect_weighted)
+            tau5 = TH.tau_scalar(sumsq, k, o.dtype, cfg.tau_factor,
+                                 cs.absdot)
+            flag, score = _detect_invariants(c5a, c6a, c7a, s5, s6, s7,
+                                             tau5, rb, cb,
+                                             cfg.detect_weighted)
 
-        if mode == "detect_only":
-            return o, T.DetectEvidence(flag.astype(jnp.int32), score)
-        if cfg.detect_only and mode != "correct":
-            det = flag.astype(jnp.int32)
-            return o, T.FaultReport(det, jnp.zeros((), jnp.int32), det)
-        detected = flag if detected is None else \
-            jnp.asarray(detected).astype(jnp.bool_).reshape(())
+            if mode == "detect_only":
+                return o, T.DetectEvidence(flag.astype(jnp.int32), score)
+            if cfg.detect_only and mode != "correct":
+                det = flag.astype(jnp.int32)
+                return o, T.FaultReport(det, jnp.zeros((), jnp.int32), det)
+            detected = flag if detected is None else \
+                jnp.asarray(detected).astype(jnp.bool_).reshape(())
 
     # ---------------- correction ladder (lax.cond branch) ----------------
     w32 = op_operand(w)
@@ -503,11 +515,11 @@ def protected_matmul(
     m = w.shape[-1]
     d2 = d.reshape(-1, k)
     if cfg is None or not cfg.enabled:
-        o = op_output(op_matmul(d2, w), d.dtype)
-        if bias is not None:
-            o = op_output(o.astype(F32) + bias.astype(F32), o.dtype)
+        with jax.named_scope("op"):
+            o = _add_bias(op_output(op_matmul(d2, w), d.dtype), bias)
         return _clean_result(o.reshape(*lead, m), mode)
 
+    pre = None
     if cfg.use_fused_kernel:
         from repro.kernels import ops as kops
         rb = pick_chunk(d2.shape[0], cfg.row_chunk)
@@ -524,32 +536,39 @@ def protected_matmul(
             # (sumsq - and so tau - also excludes the bias energy here; at
             # detection scale that undershoots the threshold by the bias'
             # share of the output energy, a no-op for bias-free sites.)
-            wck_d = wck if wck is not None \
-                else weight_checksums_matmul(w, cb)
-            cd1, cd2 = _encode_d_chunked(d2, rb)
-            cs = _scalar_checksums(cd1, cd2, wck_d)
+            with jax.named_scope("encode"):
+                wck_d = wck if wck is not None \
+                    else weight_checksums_matmul(w, cb)
+                cd1, cd2 = _encode_d_chunked(d2, rb)
+                cs = _scalar_checksums(cd1, cd2, wck_d)
             tau_a, tau_b = TH.tau_scalar_coeffs(k, d.dtype, cfg.tau_factor)
-            res = kops.abft_matmul_detect(
-                d2, w, cs.c5, cs.c6, cs.c7, cs.absdot, rb=rb, cb=cb,
-                bk=(cfg.kernel_tiles or (0, 0, 512))[2], tau_a=tau_a,
-                tau_b=tau_b, weighted=cfg.detect_weighted,
-                interpret=cfg.resolve_interpret())
+            # the kernel's epilogue compare is timed with the op
+            with jax.named_scope("op"):
+                res = kops.abft_matmul_detect(
+                    d2, w, cs.c5, cs.c6, cs.c7, cs.absdot, rb=rb, cb=cb,
+                    bk=(cfg.kernel_tiles or (0, 0, 512))[2], tau_a=tau_a,
+                    tau_b=tau_b, weighted=cfg.detect_weighted,
+                    interpret=cfg.resolve_interpret())
             if res is not None:
                 o, flag, score = res
-                return (o.reshape(*lead, m),
-                        T.DetectEvidence(jnp.max(flag), jnp.max(score)))
+                with jax.named_scope("detect"):
+                    ev = T.DetectEvidence(jnp.max(flag), jnp.max(score))
+                return o.reshape(*lead, m), ev
         # plan-pinned tile targets when profiled, else the kernel's
         # defaults; a tile that does not divide the checksum chunks
         # recombines from O instead (ops.chunk_sums_from_partials)
         bm, bn, bk = cfg.kernel_tiles or (256, 256, 512)
-        o, parts = kops.abft_matmul(
-            d2, w, interpret=cfg.resolve_interpret(), bm=bm, bn=bn, bk=bk)
-        pre = kops.chunk_sums_from_partials(parts, rb, cb, o=o)
+        with jax.named_scope("op"):
+            o, parts = kops.abft_matmul(
+                d2, w, interpret=cfg.resolve_interpret(), bm=bm, bn=bn,
+                bk=bk)
+        with jax.named_scope("detect"):
+            pre = kops.chunk_sums_from_partials(parts, rb, cb, o=o)
     else:
-        o = op_output(op_matmul(d2, w), d.dtype)
-        pre = None
-    if bias is not None:
-        o = op_output(o.astype(F32) + bias.astype(F32), o.dtype)
+        with jax.named_scope("op"):
+            o = op_output(op_matmul(d2, w), d.dtype)
+    with jax.named_scope("op"):
+        o = _add_bias(o, bias)
     o, rep = protect_matmul_output(d2, w, o, wck=wck, bias=bias, cfg=cfg,
                                    precomputed_sums=pre, mode=mode,
                                    detected=detected)
@@ -619,12 +638,18 @@ def protected_conv(
     fault); `wck` carries the precomputed (C_w1, C_w2).
     `mode`/`detected` as in protect_matmul_output.
     """
-    conv = lambda: C.conv2d(d, w, stride=stride, padding=padding, groups=groups)
+    def recompute_fn():
+        """The op and its bias add."""
+        with jax.named_scope("op"):
+            out = C.conv2d(d, w, stride=stride, padding=padding,
+                           groups=groups)
+            if bias is not None:
+                out = (out.astype(F32) + bias[None, :, None, None]
+                       .astype(F32)).astype(out.dtype)
+            return out
+
     if o is None:
-        o = conv()
-        if bias is not None:
-            o = (o.astype(F32)
-                 + bias[None, :, None, None].astype(F32)).astype(o.dtype)
+        o = recompute_fn()
     if cfg is None or not cfg.enabled:
         return _clean_result(o, mode)
 
@@ -632,17 +657,11 @@ def protected_conv(
     p = o.shape[2] * o.shape[3]
     k_eq = d.shape[1] * w.shape[2] * w.shape[3]  # Ch*R*R contraction length
 
-    cd1, cd2 = C.encode_d_conv(d)
-    if wck is None:
-        wck = C.encode_w_conv(w, groups=groups)
+    with jax.named_scope("encode"):
+        cd1, cd2 = C.encode_d_conv(d)
+        if wck is None:
+            wck = C.encode_w_conv(w, groups=groups)
     cw1, cw2 = wck
-
-    def recompute_fn():
-        out = conv()
-        if bias is not None:
-            out = (out.astype(F32)
-                   + bias[None, :, None, None].astype(F32)).astype(out.dtype)
-        return out
 
     def _bias_adjusted(cs):
         """Checksum-side bias additions (paper Table 5), the single place
@@ -679,43 +698,44 @@ def protected_conv(
     # error-free cost is the conv itself plus O(|O|) fused work.
     # the stacked checksum conv is checksum-sized (cheap) and its absdot
     # output scales every ladder threshold, so it runs in correct mode too
-    c5d, c6d, c7d, absd = C.detect_checksums_conv(
-        cd1, cd2, cw1, cw2, stride=stride, padding=padding)
-    if mode == "correct" and detected is not None:
-        # trust the carried CoC-D flag (deferred workflow): skip the
-        # O(|O|) detection sums + compare - the ladder re-derives its own
-        # sums, so the correction branch drops one full pass over O
-        detected = jnp.asarray(detected).astype(jnp.bool_).reshape(())
-    else:
-        cs0 = T.OutputChecksums(None, None, None, None, c5d, c6d, c7d)
-        if tamper_checksums is not None:
-            cs0 = tamper_checksums(cs0)
-        cs0 = _bias_adjusted(cs0)
-        # kernel_tiles carries GEMM-space (bm, bn, bk) tiles - a different
-        # tile space from the flattened-view reduction's (M-axis, payload)
-        # tiles - so the conv route always derives its own from the shape
-        s5, s6, s7, sumsq = C.detect_sums(
-            o, use_kernel=cfg.use_fused_kernel,
-            interpret=cfg.resolve_interpret())
-        tau5 = TH.tau_scalar(sumsq * jnp.ones(()), k_eq, o.dtype,
-                             cfg.tau_factor, absd)
-        tau5v = jnp.broadcast_to(tau5, (p,))
-        flag, score = _detect_invariants(cs0.c5, cs0.c6, cs0.c7,
-                                         s5, s6, s7, tau5v, n_, m_,
-                                         cfg.detect_weighted)
+    with jax.named_scope("detect"):
+        c5d, c6d, c7d, absd = C.detect_checksums_conv(
+            cd1, cd2, cw1, cw2, stride=stride, padding=padding)
+        if mode == "correct" and detected is not None:
+            # trust the carried CoC-D flag (deferred workflow): skip the
+            # O(|O|) detection sums + compare - the ladder re-derives its own
+            # sums, so the correction branch drops one full pass over O
+            detected = jnp.asarray(detected).astype(jnp.bool_).reshape(())
+        else:
+            cs0 = T.OutputChecksums(None, None, None, None, c5d, c6d, c7d)
+            if tamper_checksums is not None:
+                cs0 = tamper_checksums(cs0)
+            cs0 = _bias_adjusted(cs0)
+            # kernel_tiles carries GEMM-space (bm, bn, bk) tiles - a different
+            # tile space from the flattened-view reduction's (M-axis, payload)
+            # tiles - so the conv route always derives its own from the shape
+            s5, s6, s7, sumsq = C.detect_sums(
+                o, use_kernel=cfg.use_fused_kernel,
+                interpret=cfg.resolve_interpret())
+            tau5 = TH.tau_scalar(sumsq * jnp.ones(()), k_eq, o.dtype,
+                                 cfg.tau_factor, absd)
+            tau5v = jnp.broadcast_to(tau5, (p,))
+            flag, score = _detect_invariants(cs0.c5, cs0.c6, cs0.c7,
+                                             s5, s6, s7, tau5v, n_, m_,
+                                             cfg.detect_weighted)
 
-        if mode == "detect_only":
-            # the deferred-correction carry: raw output + compact
-            # evidence, the ladder is not even traced
-            return o, T.DetectEvidence(flag.astype(jnp.int32), score)
-        if cfg.detect_only and mode != "correct":
-            # CoC-D serving mode (same contract as the matmul path):
-            # surface the verdict, let the driver recompute; the
-            # correction ladder never enters the compiled program.
-            det = flag.astype(jnp.int32)
-            return o, T.FaultReport(det, jnp.zeros((), jnp.int32), det)
-        detected = flag if detected is None else \
-            jnp.asarray(detected).astype(jnp.bool_).reshape(())
+            if mode == "detect_only":
+                # the deferred-correction carry: raw output + compact
+                # evidence, the ladder is not even traced
+                return o, T.DetectEvidence(flag.astype(jnp.int32), score)
+            if cfg.detect_only and mode != "correct":
+                # CoC-D serving mode (same contract as the matmul path):
+                # surface the verdict, let the caller recompute; the
+                # correction ladder never enters the compiled program.
+                det = flag.astype(jnp.int32)
+                return o, T.FaultReport(det, jnp.zeros((), jnp.int32), det)
+            detected = flag if detected is None else \
+                jnp.asarray(detected).astype(jnp.bool_).reshape(())
 
     def _norm(o):
         return o.reshape(n_, m_, p)
@@ -779,9 +799,10 @@ def protected_grouped_matmul(
     flagged expert flags the op)."""
     if cfg is None or not cfg.enabled:
         dt = op_operand_dtype(d.dtype)
-        o = jnp.einsum("gnk,gkm->gnm", d.astype(dt), w.astype(dt),
-                       precision=PRECISION,
-                       preferred_element_type=F32).astype(d.dtype)
+        with jax.named_scope("op"):
+            o = jnp.einsum("gnk,gkm->gnm", d.astype(dt), w.astype(dt),
+                           precision=PRECISION,
+                           preferred_element_type=F32).astype(d.dtype)
         return _clean_result(o, mode)
 
     if wck is not None and wck.cw1.shape[0] == w.shape[0]:
@@ -797,7 +818,9 @@ def protected_grouped_matmul(
 
         o, reps = jax.vmap(one)(d, w)
     if mode == "detect_only":
-        return o, T.DetectEvidence(jnp.max(reps.flag), jnp.max(reps.score))
+        with jax.named_scope("detect"):
+            return o, T.DetectEvidence(jnp.max(reps.flag),
+                                       jnp.max(reps.score))
     rep = T.FaultReport(jnp.max(reps.detected), jnp.max(reps.corrected_by),
                         jnp.max(reps.residual))
     return o, rep

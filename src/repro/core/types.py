@@ -183,41 +183,56 @@ class ModelReport:
     forward). In deferred mode the per-layer `detected` flags are the
     detect-pass provenance - attribution survives even though correction
     happened at model granularity. Static metadata: lives in the treedef.
+
+    `scores` holds, per layer, the detect pass's `DetectEvidence.score`
+    (max |C - S| / tau; > 1 flags) where the deferred workflow has one,
+    so a caller can read how close clean traffic runs to the threshold.
     """
 
     def __init__(self, by_layer: Optional[Mapping[str, FaultReport]] = None,
-                 mode: str = "per_layer"):
+                 mode: str = "per_layer",
+                 scores: Optional[Mapping[str, jnp.ndarray]] = None):
         self.by_layer: Dict[str, FaultReport] = dict(by_layer or {})
         self.mode = mode
+        self.scores: Dict[str, jnp.ndarray] = dict(scores or {})
 
     # -- pytree protocol ---------------------------------------------------
     def tree_flatten(self):
-        keys = tuple(self.by_layer)
-        return tuple(self.by_layer[k] for k in keys), (keys, self.mode)
+        keys, skeys = tuple(self.by_layer), tuple(self.scores)
+        return (tuple(self.by_layer[k] for k in keys)
+                + tuple(self.scores[k] for k in skeys),
+                (keys, self.mode, skeys))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        keys, mode = aux
-        return cls(dict(zip(keys, children)), mode=mode)
+        keys, mode, skeys = aux
+        children = list(children)
+        return cls(dict(zip(keys, children[:len(keys)])), mode=mode,
+                   scores=dict(zip(skeys, children[len(keys):])))
 
     # -- construction ------------------------------------------------------
     def add(self, name: str, rep: "FaultReport | ModelReport") -> "ModelReport":
         """Functional append of one layer's verdict (sub-reports flatten in
         as 'name/sub')."""
-        out = dict(self.by_layer)
+        out, scores = dict(self.by_layer), dict(self.scores)
         if isinstance(rep, ModelReport):
             for sub, r in rep.by_layer.items():
                 out[f"{name}/{sub}"] = r
+            for sub, sc in rep.scores.items():
+                scores[f"{name}/{sub}"] = sc
         else:
             out[name] = rep
-        return ModelReport(out, mode=self.mode)
+        return ModelReport(out, mode=self.mode, scores=scores)
 
     def merge(self, other: "ModelReport") -> "ModelReport":
         """Union of layers; shared names merge elementwise."""
-        out = dict(self.by_layer)
+        out, scores = dict(self.by_layer), dict(self.scores)
         for name, r in other.by_layer.items():
             out[name] = FaultReport.merge(out[name], r) if name in out else r
-        return ModelReport(out, mode=self.mode)
+        for name, sc in other.scores.items():
+            scores[name] = (jnp.maximum(scores[name], sc) if name in scores
+                            else sc)
+        return ModelReport(out, mode=self.mode, scores=scores)
 
     # -- views -------------------------------------------------------------
     def __getitem__(self, name: str) -> FaultReport:

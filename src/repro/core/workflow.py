@@ -48,6 +48,10 @@ def run_ladder(
         return o, z, z
 
     def _correct(o):
+        with jax.named_scope("correct"):
+            return _ladder(o)
+
+    def _ladder(o):
         by = jnp.zeros((), jnp.int32)
         trusted = trusted_fn()
 
@@ -192,7 +196,8 @@ class ProtectedModel:
             # anyway); scan-merged paths re-detect inside the branch, and
             # inline members rerun their (deterministic) immediate ladder
             carried = {n: flags[i] > 0 for i, n in enumerate(names)}
-            with plan_scope(self.plan, mode="correct", detected=carried):
+            with jax.named_scope("correct"), plan_scope(
+                    self.plan, mode="correct", detected=carried):
                 out_c, rep = self.apply_fn(params, *args, **kwargs)
             repmap = {n: T.as_fault_report(r) for n, r in
                       self._layer_map(rep, "corrective").items()}
@@ -215,9 +220,14 @@ class ProtectedModel:
             # every member is per_layer: out_d is already fully corrected
             # and there is nothing for a model-level cond to gate
             out, by, resid = out_d, base_by, base_resid
+        # each deferred member's detect-pass score; an inline member ran
+        # its own ladder and carries none
+        zf = jnp.zeros((), jnp.float32)
         rep = T.ModelReport(
             {n: T.FaultReport(flags[i], by[i], resid[i])
-             for i, n in enumerate(names)}, mode="deferred")
+             for i, n in enumerate(names)}, mode="deferred",
+            scores={n: zf if n in inline else evmap[n].score
+                    for n in names})
         # out_d is the detect pass's raw output: equal to `out` on the
         # clean path (the cond returns it untouched), the *faulty* values
         # on a corrective rerun - so out vs out_d localizes which rows a

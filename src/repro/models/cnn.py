@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from repro.core import (DEFAULT_CONFIG, ModelReport, ProtectConfig,
                         ProtectedModel, ProtectionPlan, build_plan,
                         conv_entry, protect_site, resolve_entry)
-from repro.core.plan import ambient_plan
+from repro.core.plan import ambient_plan, current_path
 from repro.core.protected import op_matmul
 
 F32 = jnp.float32
@@ -165,7 +165,7 @@ def _forward_pass(params: Dict, x: jnp.ndarray, cfg: CNNConfig,
                            (DEFAULT_CONFIG if cfg.abft else
                             DEFAULT_CONFIG.replace(enabled=False))),
                 stride=spec.stride, pad=spec.pad)
-        o = _injected_output(i, x, params[name], spec, inject_layer,
+        o = _injected_output(i, name, x, params[name], spec, inject_layer,
                              inject_o)
         y, r = protect_site(name,
                             (x, params[name]["w"], params[name]["b"]),
@@ -258,13 +258,22 @@ def _conv_output(x: jnp.ndarray, p: Dict, spec: ConvSpec) -> jnp.ndarray:
     return (o.astype(F32) + p["b"][None, :, None, None]).astype(o.dtype)
 
 
-def _injected_output(i: int, x, p: Dict, spec: ConvSpec, inject_layer,
-                     inject_o):
-    """Layer i's output under the injection hook (None: not injected)."""
+def _injected_output(i: int, name: str, x, p: Dict, spec: ConvSpec,
+                     inject_layer, inject_o):
+    """Layer i's output under the injection hook (None: not injected),
+    under the site's scope: its conv as the site's `op`, the choice of
+    the planted output as `inject`. The choice's compare is traced
+    before the conv: traced after it, the corrective rerun's operands
+    come in another order and the step's HLO changes."""
     if not inject_o or i not in inject_o:
         return None
-    return jnp.where(inject_layer == i, inject_o[i],
-                     _conv_output(x, p, spec))
+    with jax.named_scope(current_path(name)):
+        with jax.named_scope("inject"):
+            hit = inject_layer == i
+        with jax.named_scope("op"):
+            o = _conv_output(x, p, spec)
+        with jax.named_scope("inject"):
+            return jnp.where(hit, inject_o[i], o)
 
 
 def conv_output_at(params: Dict, x: jnp.ndarray, cfg: CNNConfig,

@@ -288,6 +288,36 @@ def test_plan_forward_injection_attributed_to_layer():
                                rtol=1e-3, atol=1e-3)
 
 
+def test_deferred_report_keeps_detect_scores():
+    """The deferred report keeps each site's detect-pass score: far below
+    1 on clean traffic, above 1 at an injected site, and 0 for a site
+    that ran its own ladder inline (it has no detect-pass score)."""
+    cfg, params, x = _model()
+    plan = core.build_plan(params, cfg, batch=2)
+    layer = 2
+    _, o_clean = cnn.conv_output_at(params, x, cfg, layer)
+    p = inj.plan(jax.random.PRNGKey(11), o_clean.shape[0], o_clean.shape[1],
+                 max_elems=64)
+    o_bad = inj.inject_conv(o_clean, p)
+    _, rep = cnn.forward_cnn(params, x, cfg, plan=plan,
+                             correction="deferred")
+    assert list(rep.scores) == list(rep.by_layer)
+    assert all(0 <= float(s) < 1 for s in rep.scores.values()), rep.scores
+    _, rep = cnn.forward_cnn(params, x, cfg, plan=plan, inject_layer=layer,
+                             inject_o={layer: o_bad}, correction="deferred")
+    assert float(rep.scores[f"conv{layer}"]) > 1
+    assert all(float(s) < 1 for n, s in rep.scores.items()
+               if n != f"conv{layer}")
+    inline = dataclasses.replace(plan, entries={
+        **plan.entries,
+        "conv0": dataclasses.replace(plan.entries["conv0"],
+                                     execution="per_layer")})
+    _, rep = cnn.forward_cnn(params, x, cfg, plan=inline,
+                             correction="deferred")
+    assert float(rep.scores["conv0"]) == 0.0
+    assert float(rep.scores["conv1"]) > 0.0
+
+
 # --------------------------------------------------------------------------
 # ModelReport semantics
 # --------------------------------------------------------------------------
@@ -322,6 +352,24 @@ def test_model_report_is_pytree():
     assert len(leaves) == 3  # one FaultReport = 3 scalar leaves
     rebuilt = jax.tree_util.tree_unflatten(tree, leaves)
     assert rebuilt.by_layer.keys() == rep.by_layer.keys()
+
+
+def test_model_report_scores_ride_the_pytree():
+    """Scores are leaves beside the verdicts: they cross a jit boundary,
+    and add/merge keep them (nested under the prefix, max on a clash)."""
+    clean = core.FaultReport.clean()
+    rep = core.ModelReport({"a": clean}, mode="deferred",
+                           scores={"a": jnp.float32(0.25)})
+    leaves, tree = jax.tree_util.tree_flatten(rep)
+    assert len(leaves) == 4
+    rebuilt = jax.jit(lambda r: r)(rep)
+    assert rebuilt.mode == "deferred"
+    assert float(rebuilt.scores["a"]) == 0.25
+    other = core.ModelReport({"a": clean}, scores={"a": jnp.float32(2.0)})
+    assert float(rep.merge(other).scores["a"]) == 2.0
+    nested = core.ModelReport({"b": clean}).add("blk", rep)
+    assert float(nested.scores["blk/a"]) == 0.25
+    assert core.ModelReport({"a": clean}).scores == {}
 
 
 # --------------------------------------------------------------------------
