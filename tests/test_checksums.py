@@ -111,3 +111,102 @@ def test_bf16_no_false_positive(seed):
     o = jnp.dot(d, w, preferred_element_type=jnp.float32).astype(jnp.bfloat16)
     _, rep = protect_matmul_output(d, w, o)
     assert int(rep.detected) == 0
+
+
+# --------------------------------------------------------------------------
+# checksum_conv's im2col: strided taps without gathers
+# --------------------------------------------------------------------------
+
+def _indexed_im2col_conv(x, f, stride, padding, groups):
+    """The reference: checksum_conv with numpy-indexed taps
+    (`xp[..., i::stride, j::stride]`), which lowers to gathers at
+    stride > 1. checksum_conv must keep its values bitwise."""
+    b, c, h, w = x.shape
+    nf, cg, r, s = f.shape
+    (pt, pb), (pl, pr) = C._window_pads((h, w), (r, s), stride, padding)
+    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, 0), (pt, pb), (pl, pr)))
+    e1 = (h + pt + pb - r) // stride + 1
+    e2 = (w + pl + pr - s) // stride + 1
+    cols = [xp[:, :, i:i + stride * (e1 - 1) + 1:stride,
+               j:j + stride * (e2 - 1) + 1:stride]
+            for i in range(r) for j in range(s)]
+    pat = jnp.stack(cols, axis=2).reshape(b, groups, cg * r * s, e1 * e2)
+    fm = f.astype(jnp.float32).reshape(groups, nf // groups, cg * r * s)
+    out = jnp.einsum("bgkp,gfk->bgfp", pat, fm, precision=C.PRECISION)
+    return out.reshape(b, nf, e1, e2)
+
+
+# (x shape, f shape, stride, padding): resnet18's conv0 (7x7/2, pad 3),
+# its 3x3/2 downsampling convs (conv5/9/13), alexnet's 11x11/4 stem, and
+# a stride-1 site (conv1-conv4).
+SITES = {
+    "conv0": ((3, 3, 224, 224), (3, 3, 7, 7), 2, ((3, 3), (3, 3))),
+    "3x3s2": ((3, 64, 56, 56), (3, 64, 3, 3), 2, ((1, 1), (1, 1))),
+    "11x11s4": ((3, 3, 224, 224), (3, 3, 11, 11), 4, ((2, 2), (2, 2))),
+    "3x3s1": ((3, 64, 56, 56), (3, 64, 3, 3), 1, ((1, 1), (1, 1))),
+}
+
+
+@pytest.mark.parametrize("site", sorted(SITES))
+def test_checksum_conv_lowers_to_no_gather(site):
+    """No `gather` anywhere, and no slice strided along the minor (lane)
+    axis: numpy-style strided indexing lowers to gathers, which a TPU ran
+    ~200x slower than a pass over the patches, and lane-strided slices
+    still ~20x slower than a stride-1 site's whole check."""
+    from jaxpr_walk import eqns as walk_eqns
+    xs, fs, stride, padding = SITES[site]
+    jaxpr = jax.make_jaxpr(
+        lambda x, f: C.checksum_conv(x, f, stride, padding))(
+            jnp.zeros(xs, jnp.float32), jnp.zeros(fs, jnp.float32))
+    eqs = walk_eqns(jaxpr.jaxpr)
+    assert "gather" not in [e.primitive.name for e in eqs]
+    slices = [e for e in eqs if e.primitive.name == "slice"]
+    assert len(slices) >= fs[2] * fs[3]          # one per tap
+    assert all((e.params["strides"] or (1,))[-1] == 1 for e in slices)
+
+
+@pytest.mark.parametrize("xs,fs,stride,padding,groups", [
+    ((2, 3, 9, 9), (4, 3, 3, 3), 1, "SAME", 1),
+    ((2, 3, 10, 10), (4, 3, 3, 3), 2, "SAME", 1),
+    ((2, 4, 11, 11), (3, 4, 2, 2), 2, "VALID", 1),
+    ((2, 3, 35, 35), (4, 3, 11, 11), 4, "VALID", 1),
+    ((2, 3, 32, 30), (5, 3, 5, 4), 4, ((2, 1), (0, 3)), 1),
+    ((2, 8, 15, 15), (6, 4, 3, 3), 2, "SAME", 2),
+    ((1, 8, 12, 12), (8, 2, 3, 3), 1, "VALID", 4),
+    SITES["conv0"] + (1,),
+    SITES["3x3s2"] + (1,),
+])
+def test_checksum_conv_matches_indexed_im2col_bitwise(xs, fs, stride,
+                                                     padding, groups):
+    """The phase-built patches give bitwise the values of the
+    numpy-indexed im2col, and the conv primitive's within f32 rounding."""
+    key = jax.random.PRNGKey(sum(xs) + stride)
+    x = rand(key, xs, jnp.float32)
+    f = rand(jax.random.fold_in(key, 1), fs, jnp.float32)
+    got = jax.jit(lambda x, f: C.checksum_conv(x, f, stride, padding,
+                                               groups))(x, f)
+    ref = jax.jit(lambda x, f: _indexed_im2col_conv(x, f, stride, padding,
+                                                    groups))(x, f)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    conv = jax.lax.conv_general_dilated(
+        x, f, (stride, stride), padding, dimension_numbers=C._DN,
+        feature_group_count=groups, precision=jax.lax.Precision.HIGHEST)
+    scale = float(jnp.max(jnp.abs(conv)))
+    np.testing.assert_allclose(got, conv, rtol=1e-5, atol=1e-5 * scale)
+
+
+@pytest.mark.parametrize("xs,fs,padding,groups", [
+    SITES["3x3s1"][:2] + (SITES["3x3s1"][3], 1),
+    ((3, 512, 7, 7), (3, 512, 3, 3), ((1, 1), (1, 1)), 1),
+    ((2, 8, 12, 12), (6, 4, 3, 3), "VALID", 2),
+])
+def test_stride1_checksum_conv_lowering_unchanged(xs, fs, padding, groups):
+    """At stride 1 the lowered checksum conv is the numpy-indexed one's,
+    op for op: stride-1 sites run the same program as before."""
+    def lowered(impl):
+        def site(x, f):
+            return impl(x, f, 1, padding, groups)
+        return jax.jit(site).lower(
+            jax.ShapeDtypeStruct(xs, jnp.float32),
+            jax.ShapeDtypeStruct(fs, jnp.float32)).as_text(debug_info=False)
+    assert lowered(C.checksum_conv) == lowered(_indexed_im2col_conv)
